@@ -1,49 +1,30 @@
-"""Version-compatibility shims for the narrow slice of JAX API we need.
+"""The narrow slice of JAX mesh API the repo uses, in one place (JAX 0.9).
 
-``jax.shard_map`` (with its ``check_vma`` kwarg) and ``jax.sharding.AxisType``
-only exist on recent JAX; older releases ship ``shard_map`` under
-``jax.experimental.shard_map`` with a ``check_rep`` kwarg and meshes without
-axis types.  Everything in the repo imports these two helpers instead of
-guessing the JAX version at each call site.
+Everything imports ``shard_map`` and ``make_mesh`` from here, so a future
+API move is one edit.
 """
 from __future__ import annotations
 
-import inspect
 import os
 
 import jax
 
-try:  # JAX >= 0.6 style
-    from jax import shard_map as _shard_map
-except ImportError:  # pragma: no cover - depends on installed JAX
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-_SHARD_MAP_PARAMS = frozenset(inspect.signature(_shard_map).parameters)
-
 
 def shard_map(f, *, mesh, in_specs, out_specs, check_vma=None, **kw):
-    """``jax.shard_map`` with ``check_vma`` translated for old releases.
-
-    ``check_vma`` (new name) and ``check_rep`` (old name) both toggle the
-    replication-checking machinery; sparse collectives and ppermute chains
-    are not representable in it, so the hot paths pass False.
-    """
+    """``jax.shard_map``.  ``check_vma`` toggles the replication-checking
+    machinery; sparse collectives and ppermute chains are not
+    representable in it, so the hot paths pass False."""
     if check_vma is not None:
-        if "check_vma" in _SHARD_MAP_PARAMS:
-            kw["check_vma"] = check_vma
-        elif "check_rep" in _SHARD_MAP_PARAMS:
-            kw["check_rep"] = check_vma
-    return _shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                      **kw)
+        kw["check_vma"] = check_vma
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, **kw)
 
 
 def make_mesh(shape, axis_names):
-    """``jax.make_mesh`` with Auto axis types where the API supports them."""
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is not None:
-        return jax.make_mesh(shape, axis_names,
-                             axis_types=(axis_type.Auto,) * len(axis_names))
-    return jax.make_mesh(shape, axis_names)
+    """``jax.make_mesh`` with Auto axis types (GSPMD-partitioned axes)."""
+    return jax.make_mesh(
+        shape, axis_names,
+        axis_types=(jax.sharding.AxisType.Auto,) * len(axis_names))
 
 
 def ensure_fake_host_devices(n: int = 8) -> None:

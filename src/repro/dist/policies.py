@@ -96,6 +96,19 @@ class Policy:
                                     self._leaf_spec(x.shape, stacked=stacked)),
             tree)
 
+    def attention_specs(self, q_shape, kv_shape):
+        """(q_spec, kv_spec) for an attention kernel on (B, S, heads, Dh)
+        operands run under shard_map: batch over the batch axes, heads over
+        the tensor axes only where both head counts divide (each shard
+        then keeps whole GQA groups); sequence and head_dim whole."""
+        b = self.batch_axes or None
+        if not (b and q_shape[0] % self.axis_size(self.batch_axes) == 0):
+            b = None
+        n = self.axis_size(self.tensor_axes)
+        t = (self.tensor_axes or None) if (
+            n > 1 and q_shape[2] % n == 0 and kv_shape[2] % n == 0) else None
+        return P(b, None, t, None), P(b, None, t, None)
+
     # -- activation constraints --------------------------------------------
 
     def _dim_ok(self, shape, i, axes) -> bool:

@@ -50,7 +50,7 @@ def gather_kv_pages(pages, page_table, *, contiguous=False):
 def _paged_kernel(pt_ref, kl_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref,
                   m_scr, l_scr, acc_scr, *, ps, np_, scale):
     b = pl.program_id(0)
-    j = pl.program_id(2)
+    j = pl.program_id(1)
 
     @pl.when(j == 0)
     def _init():
@@ -62,29 +62,31 @@ def _paged_kernel(pt_ref, kl_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref,
 
     @pl.when(j * ps < kvl)  # pages fully past kv_len issue no MXU work
     def _compute():
-        q = q_ref[0, 0].astype(jnp.float32) * scale   # (G, Dh)
-        k = k_ref[0, :, 0].astype(jnp.float32)        # (ps, Dh)
-        v = v_ref[0, :, 0].astype(jnp.float32)
+        # q rows are block-diagonal over the KH heads' lanes, so one
+        # (H, KH*Dh) x (KH*Dh, ps) matmul gives every head its own scores
+        q = q_ref[0].astype(jnp.float32) * scale      # (H, KH*Dh)
+        k = k_ref[0].astype(jnp.float32)              # (ps, KH*Dh)
+        v = v_ref[0].astype(jnp.float32)
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32)
         kpos = j * ps + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
         s = jnp.where(kpos < kvl, s, NEG_INF)
-        m_prev = m_scr[...][:, 0]
-        m_new = jnp.maximum(m_prev, s.max(axis=-1))
-        p = jnp.where(kpos < kvl, jnp.exp(s - m_new[:, None]), 0.0)
+        m_prev = m_scr[...]                           # (H, 1)
+        m_new = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))
+        p = jnp.where(kpos < kvl, jnp.exp(s - m_new), 0.0)
         corr = jnp.exp(m_prev - m_new)
-        l_scr[...] = (l_scr[...][:, 0] * corr + p.sum(axis=-1))[:, None]
+        l_scr[...] = l_scr[...] * corr + p.sum(axis=-1, keepdims=True)
         pv = jax.lax.dot_general(p, v, (((1,), (0,)), ((), ())),
                                  preferred_element_type=jnp.float32)
-        acc_scr[...] = acc_scr[...] * corr[:, None] + pv
-        m_scr[...] = m_new[:, None]
+        acc_scr[...] = acc_scr[...] * corr + pv
+        m_scr[...] = m_new
 
     @pl.when(j == np_ - 1)
     def _finalize():
-        l = jnp.maximum(l_scr[...][:, 0], 1e-20)
-        o_ref[0, 0] = (acc_scr[...] / l[:, None]).astype(o_ref.dtype)
-        m_ref[0, 0] = m_scr[...][:, 0].astype(m_ref.dtype)
-        l_ref[0, 0] = l.astype(l_ref.dtype)
+        l = jnp.maximum(l_scr[...], 1e-20)
+        o_ref[0] = (acc_scr[...] / l).astype(o_ref.dtype)
+        m_ref[0] = m_scr[...]
+        l_ref[0] = l
 
 
 def paged_decode_attention_pallas(q, k_pages, v_pages, page_table, kv_len, *,
@@ -102,6 +104,13 @@ def paged_decode_attention_pallas(q, k_pages, v_pages, page_table, kv_len, *,
     (``PrefetchScalarGridSpec``): the grid's page step j DMAs physical
     page ``page_table[b, j]`` directly from HBM — the gather never
     materializes a contiguous KV copy.
+
+    One grid step reads a whole page, all KH heads, as a (ps, KH*Dh)
+    tile (a free view of the pool), so every block's last two dims are
+    full array dims whatever KH is.  q is laid out block-diagonally: row
+    i holds head i's query in kv head i // G's Dh lanes and zeros
+    elsewhere.  The scores then need no per-head slicing, and head i's
+    output is the kv-head-(i // G) lane block of its row.
     """
     B, Sq, H, Dh = q.shape
     NP, ps, KH, _ = k_pages.shape
@@ -109,42 +118,46 @@ def paged_decode_attention_pallas(q, k_pages, v_pages, page_table, kv_len, *,
     G = H // KH
     scale = softmax_scale if softmax_scale is not None else Dh ** -0.5
 
-    qt = q.reshape(B, KH, G, Dh)  # Sq == 1
+    own = (jnp.arange(H)[:, None] // G) == jnp.arange(KH)[None, :]  # (H,KH)
+    qbd = jnp.where(own[None, :, :, None], q[:, 0, :, None, :],
+                    jnp.zeros((), q.dtype)).reshape(B, H, KH * Dh)
     kern = functools.partial(_paged_kernel, ps=ps, np_=P, scale=scale)
+    page = lambda b, j, pt, kl: (pt[b, j], 0, 0)
+    row = lambda b, j, pt, kl: (b, 0, 0)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=(B, KH, P),
+        grid=(B, P),
         in_specs=[
-            pl.BlockSpec((1, 1, G, Dh), lambda b, h, j, pt, kl: (b, h, 0, 0)),
-            pl.BlockSpec((1, ps, 1, Dh),
-                         lambda b, h, j, pt, kl: (pt[b, j], 0, h, 0)),
-            pl.BlockSpec((1, ps, 1, Dh),
-                         lambda b, h, j, pt, kl: (pt[b, j], 0, h, 0)),
+            pl.BlockSpec((1, H, KH * Dh), row),
+            pl.BlockSpec((1, ps, KH * Dh), page),
+            pl.BlockSpec((1, ps, KH * Dh), page),
         ],
         out_specs=[
-            pl.BlockSpec((1, 1, G, Dh), lambda b, h, j, pt, kl: (b, h, 0, 0)),
-            pl.BlockSpec((1, 1, G), lambda b, h, j, pt, kl: (b, h, 0)),
-            pl.BlockSpec((1, 1, G), lambda b, h, j, pt, kl: (b, h, 0)),
+            pl.BlockSpec((1, H, KH * Dh), row),
+            pl.BlockSpec((1, H, 1), row),
+            pl.BlockSpec((1, H, 1), row),
         ],
         scratch_shapes=[
-            pltpu.VMEM((G, 1), jnp.float32),
-            pltpu.VMEM((G, 1), jnp.float32),
-            pltpu.VMEM((G, Dh), jnp.float32),
+            pltpu.VMEM((H, 1), jnp.float32),
+            pltpu.VMEM((H, 1), jnp.float32),
+            pltpu.VMEM((H, KH * Dh), jnp.float32),
         ],
     )
     out, m, l = pl.pallas_call(
         kern,
         grid_spec=grid_spec,
         out_shape=[
-            jax.ShapeDtypeStruct((B, KH, G, Dh), q.dtype),
-            jax.ShapeDtypeStruct((B, KH, G), jnp.float32),
-            jax.ShapeDtypeStruct((B, KH, G), jnp.float32),
+            jax.ShapeDtypeStruct((B, H, KH * Dh), q.dtype),
+            jax.ShapeDtypeStruct((B, H, 1), jnp.float32),
+            jax.ShapeDtypeStruct((B, H, 1), jnp.float32),
         ],
         interpret=interpret,
-    )(page_table.astype(jnp.int32), kv_len.astype(jnp.int32), qt,
-      k_pages, v_pages)
-    return (out.reshape(B, 1, H, Dh), m.reshape(B, 1, KH, G),
-            l.reshape(B, 1, KH, G))
+    )(page_table.astype(jnp.int32), kv_len.astype(jnp.int32), qbd,
+      k_pages.reshape(NP, ps, KH * Dh), v_pages.reshape(NP, ps, KH * Dh))
+    heads = jnp.arange(KH)
+    out = out.reshape(B, KH, G, KH, Dh)[:, heads, :, heads]  # (KH,B,G,Dh)
+    return (out.transpose(1, 0, 2, 3).reshape(B, 1, H, Dh),
+            m.reshape(B, 1, KH, G), l.reshape(B, 1, KH, G))
 
 
 def _kernel(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
@@ -178,6 +191,8 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
         qpos = q_start + jax.lax.broadcasted_iota(jnp.int32, (bq, bkv), 0)
         kpos = k_start + jax.lax.broadcasted_iota(jnp.int32, (bq, bkv), 1)
         mask = jnp.ones((bq, bkv), jnp.bool_)
+        if skv % bkv:  # zero-padded tail keys
+            mask &= kpos < skv
         if causal:
             mask &= kpos <= qpos
         if window:
@@ -202,15 +217,23 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
 def flash_attention_pallas(q, k, v, *, causal=True, window=0, q_offset=0,
                            softmax_scale=None, block_q=128, block_kv=128,
                            interpret=False):
-    """q: (B, Sq, H, Dh); k, v: (B, Skv, KH, Dh) — same API as ref."""
+    """q: (B, Sq, H, Dh); k, v: (B, Skv, KH, Dh) — same API as ref.
+
+    Lengths past one block that are not a multiple of it are zero-padded
+    to one (padded keys are masked, padded query rows dropped)."""
     B, Sq, H, Dh = q.shape
     _, Skv, KH, _ = k.shape
     G = H // KH
     scale = softmax_scale if softmax_scale is not None else Dh ** -0.5
     bq = min(block_q, Sq)
     bkv = min(block_kv, Skv)
-    assert Sq % bq == 0 and Skv % bkv == 0, (Sq, bq, Skv, bkv)
-    nq, nkv = Sq // bq, Skv // bkv
+    pad_q, pad_kv = (-Sq) % bq, (-Skv) % bkv
+    if pad_q:
+        q = jnp.pad(q, ((0, 0), (0, pad_q), (0, 0), (0, 0)))
+    if pad_kv:
+        k = jnp.pad(k, ((0, 0), (0, pad_kv), (0, 0), (0, 0)))
+        v = jnp.pad(v, ((0, 0), (0, pad_kv), (0, 0), (0, 0)))
+    nq, nkv = (Sq + pad_q) // bq, (Skv + pad_kv) // bkv
 
     qt = q.transpose(0, 2, 1, 3)  # (B, H, Sq, Dh)
     kt = k.transpose(0, 2, 1, 3)  # (B, KH, Skv, Dh)
@@ -229,7 +252,7 @@ def flash_attention_pallas(q, k, v, *, causal=True, window=0, q_offset=0,
             pl.BlockSpec((1, 1, bkv, Dh), lambda b, h, i, j: (b, h, j, 0)),
         ],
         out_specs=pl.BlockSpec((1, G, bq, Dh), lambda b, h, i, j: (b, h, i, 0)),
-        out_shape=jax.ShapeDtypeStruct((B, H, Sq, Dh), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((B, H, Sq + pad_q, Dh), q.dtype),
         scratch_shapes=[
             pltpu.VMEM((G, bq), jnp.float32),
             pltpu.VMEM((G, bq), jnp.float32),
@@ -237,4 +260,4 @@ def flash_attention_pallas(q, k, v, *, causal=True, window=0, q_offset=0,
         ],
         interpret=interpret,
     )(qt, kt, vt)
-    return out.transpose(0, 2, 1, 3)
+    return out.transpose(0, 2, 1, 3)[:, :Sq]

@@ -1,10 +1,14 @@
 """jit'd dispatch wrappers: Pallas on TPU, blockwise-jnp elsewhere.
 
 Every op takes ``impl`` in {None, "pallas", "jnp", "ref"}; None = auto
-(pallas iff running on TPU).  ``interpret=True`` is used automatically when
+(pallas iff running on TPU, unless ``forced_route`` says otherwise while
+a program is traced).  ``interpret=True`` is used automatically when
 "pallas" is forced on a non-TPU backend (kernel correctness tests).
 """
 from __future__ import annotations
+
+import contextlib
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -18,9 +22,29 @@ from repro.kernels.ssd_scan import ssd_pallas
 from repro.kernels.topk_compress import topk_compress_pallas
 
 
+_forced = None
+
+
+@contextlib.contextmanager
+def forced_route(impl):
+    """Route every op with ``impl=None`` to ``impl`` for programs TRACED
+    inside the block (routing is decided at trace time, so jit a fresh
+    function here).  Used to build the jnp twin of a Pallas program on the
+    same device, e.g. for an agreement check."""
+    global _forced
+    assert impl in ("pallas", "jnp", "ref"), impl
+    prev, _forced = _forced, impl
+    try:
+        yield
+    finally:
+        _forced = prev
+
+
 def _route(impl):
     if impl in ("pallas", "jnp", "ref"):
         return impl
+    if _forced is not None:
+        return _forced
     return "pallas" if jax.default_backend() == "tpu" else "jnp"
 
 
@@ -28,13 +52,50 @@ def _interp():
     return jax.default_backend() != "tpu"
 
 
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
+def _flash_pallas(q, k, v, causal, window, q_offset, softmax_scale,
+                  interpret):
+    """Pallas flash forward; the backward is XLA: the VJP of
+    ``ref.flash_attention_jnp`` recomputed from the saved q/k/v."""
+    return flash_attention_pallas(
+        q, k, v, causal=causal, window=window, q_offset=q_offset,
+        softmax_scale=softmax_scale, interpret=interpret)
+
+
+def _flash_pallas_fwd(q, k, v, causal, window, q_offset, softmax_scale,
+                      interpret):
+    out = _flash_pallas(q, k, v, causal, window, q_offset, softmax_scale,
+                        interpret)
+    return out, (q, k, v)
+
+
+def _flash_pallas_bwd(causal, window, q_offset, softmax_scale, interpret,
+                      res, g):
+    _, vjp = jax.vjp(functools.partial(
+        ref.flash_attention_jnp, causal=causal, window=window,
+        q_offset=q_offset, softmax_scale=softmax_scale), *res)
+    return vjp(g)
+
+
+_flash_pallas.defvjp(_flash_pallas_fwd, _flash_pallas_bwd)
+
+
 def flash_attention(q, k, v, *, causal=True, window=0, q_offset=0,
-                    kv_len=None, softmax_scale=None, impl=None):
+                    kv_len=None, softmax_scale=None, impl=None, policy=None):
+    """``policy``: the caller's sharding policy.  Under a mesh the Pallas
+    kernel runs inside a shard_map over the policy's attention specs:
+    Mosaic kernels cannot be partitioned automatically."""
     r = _route(impl)
     if r == "pallas" and kv_len is None:
-        return flash_attention_pallas(
-            q, k, v, causal=causal, window=window, q_offset=q_offset,
+        fn = functools.partial(
+            _flash_pallas, causal=causal, window=window, q_offset=q_offset,
             softmax_scale=softmax_scale, interpret=_interp())
+        if policy is not None and policy.mesh is not None:
+            from repro.dist.compat import shard_map
+            qs, kvs = policy.attention_specs(q.shape, k.shape)
+            fn = shard_map(fn, mesh=policy.mesh, in_specs=(qs, kvs, kvs),
+                           out_specs=qs, check_vma=False)
+        return fn(q, k, v)
     if r == "ref":
         return ref.attention_ref(q, k, v, causal=causal, window=window,
                                  q_offset=q_offset, kv_len=kv_len,

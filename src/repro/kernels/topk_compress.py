@@ -58,24 +58,25 @@ def _mask_tile(x, theta, masked_ref, resid_ref, *, block, rows):
     # full keep.sum recount pass.
     keep = keep | ((mag >= hi0) & (hi0 == 0.0))
     masked = jnp.where(keep, x, 0.0)
-    masked_ref[0] = masked.astype(masked_ref.dtype)
+    masked_ref[0, 0] = masked.astype(masked_ref.dtype)
     # residual via the SAME mask (bit-identical to x - masked: kept lanes
     # give x - x == +0.0, dropped lanes x - 0 == x) — reads the i1 mask
     # instead of a second f32 pass over masked.
-    resid_ref[0] = jnp.where(keep, 0.0, x).astype(resid_ref.dtype)
+    resid_ref[0, 0] = jnp.where(keep, 0.0, x).astype(resid_ref.dtype)
 
 
 def _kernel(theta_ref, x_ref, masked_ref, resid_ref, *, block, rows):
-    _mask_tile(x_ref[0].astype(jnp.float32), theta_ref[0, 0],
-               masked_ref, resid_ref, block=block, rows=rows)
+    _mask_tile(x_ref[0, 0].astype(jnp.float32),
+               theta_ref[pl.program_id(0)], masked_ref, resid_ref,
+               block=block, rows=rows)
 
 
 def _kernel_ef(theta_ref, x_ref, ef_ref, masked_ref, resid_ref, *, block,
                rows):
     # fused error-feedback add: f32 only inside the VMEM tile
-    x = x_ref[0].astype(jnp.float32) + ef_ref[0].astype(jnp.float32)
-    _mask_tile(x, theta_ref[0, 0], masked_ref, resid_ref, block=block,
-               rows=rows)
+    x = x_ref[0, 0].astype(jnp.float32) + ef_ref[0, 0].astype(jnp.float32)
+    _mask_tile(x, theta_ref[pl.program_id(0)], masked_ref, resid_ref,
+               block=block, rows=rows)
 
 
 def _pick_rows(nb: int, rows: int, itemsize: int, block: int = 1024) -> int:
@@ -87,8 +88,9 @@ def _pick_rows(nb: int, rows: int, itemsize: int, block: int = 1024) -> int:
     16-iteration bisection preamble; fat tiles amortize it and keep the
     DMA pipeline busy).  Worst case VMEM: 4 tiles (x, ef, masked, resid)
     x 2 double-buffered = 2 MiB, far under the ~16 MiB budget.  Falling
-    back to smaller divisors keeps any nb legal (pallas pads sub-tile
-    shapes, at some efficiency cost).
+    back to smaller divisors keeps any nb legal: the caller views the
+    blocks as (R, nb // rows, rows, block), so a tile's last two dims are
+    always the array's full dims (the TPU block-shape rule).
     """
     floor = max(rows, (4 * rows) // max(itemsize, 1))
     target = max(floor, (1 << 18) // max(block * itemsize, 1))
@@ -111,20 +113,21 @@ def topk_compress_pallas(x, theta, *, ef=None, block=1024, rows=8,
     assert L % block == 0, (L, block)
     nb = L // block
     rows = _pick_rows(nb, rows, jnp.dtype(x.dtype).itemsize, block)
-    xb = x.reshape(R, nb, block)
-    theta2 = theta.reshape(R, 1).astype(jnp.float32)
+    shape4 = (R, nb // rows, rows, block)
+    xb = x.reshape(shape4)
 
-    tile = lambda: pl.BlockSpec((1, rows, block), lambda r, i: (r, i, 0))
-    in_specs = [pl.BlockSpec((1, 1), lambda r, i: (r, 0),
-                             memory_space=pltpu.SMEM), tile()]
-    args = [theta2, xb]
+    # theta rides whole in SMEM (R scalars), indexed by the replica axis.
+    tile = lambda: pl.BlockSpec((1, 1, rows, block),
+                                lambda r, i: (r, i, 0, 0))
+    in_specs = [pl.BlockSpec(memory_space=pltpu.SMEM), tile()]
+    args = [theta.astype(jnp.float32), xb]
     resid_dtype = x.dtype
     if ef is None:
         kern = functools.partial(_kernel, block=block, rows=rows)
     else:
         kern = functools.partial(_kernel_ef, block=block, rows=rows)
         in_specs.append(tile())
-        args.append(ef.reshape(R, nb, block))
+        args.append(ef.reshape(shape4))
         resid_dtype = ef.dtype
     masked, resid = pl.pallas_call(
         kern,
@@ -132,8 +135,8 @@ def topk_compress_pallas(x, theta, *, ef=None, block=1024, rows=8,
         in_specs=in_specs,
         out_specs=[tile(), tile()],
         out_shape=[
-            jax.ShapeDtypeStruct((R, nb, block), x.dtype),
-            jax.ShapeDtypeStruct((R, nb, block), resid_dtype),
+            jax.ShapeDtypeStruct(shape4, x.dtype),
+            jax.ShapeDtypeStruct(shape4, resid_dtype),
         ],
         interpret=interpret,
     )(*args)

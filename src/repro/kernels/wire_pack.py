@@ -169,6 +169,14 @@ def _fdot(a, b):
     return jax.lax.dot(a, b, precision=jax.lax.Precision.HIGHEST)
 
 
+def _prefix_count(bits):
+    """Inclusive prefix sum along lanes of a (rows, n) 0/1 f32 array, as a
+    triangular-ones matmul (Mosaic has no cumsum; exact for n <= 2^24)."""
+    n = bits.shape[-1]
+    return _fdot(bits, (_iota((n, n), 0) <= _iota((n, n), 1))
+                 .astype(jnp.float32))
+
+
 def _pack_p4_tile(off, *, wb, k_b, rows):
     """(rows, k_b) int32 ascending -> (rows, nbytes) f32 of byte values."""
     k_pairs = (k_b + 1) // 2
@@ -185,10 +193,16 @@ def _pack_p4_tile(off, *, wb, k_b, rows):
     return jnp.concatenate([lo_b, bm], axis=-1)
 
 
+def _to_u8(x):
+    """f32 byte values -> uint8.  Mosaic casts to and from 8-bit integers
+    only through int32."""
+    return x.astype(jnp.int32).astype(jnp.uint8)
+
+
 def _pack_p4_kernel(off_ref, out_ref, *, wb, k_b, rows):
-    out = _pack_p4_tile(off_ref[0].astype(jnp.int32), wb=wb, k_b=k_b,
+    out = _pack_p4_tile(off_ref[0, 0].astype(jnp.int32), wb=wb, k_b=k_b,
                         rows=rows)
-    out_ref[0] = out.astype(jnp.uint8)
+    out_ref[0, 0] = _to_u8(out)
 
 
 def _unpack_p4_tile(pf, *, wb, k_b, rows):
@@ -210,8 +224,7 @@ def _unpack_p4_tile(pf, *, wb, k_b, rows):
                                        .astype(jnp.float32)))
     bits = bsh - 2.0 * jnp.floor(bsh / 2.0)  # (rows, P) in {0, 1}
     # rank = inclusive prefix count of set bits (triangular-ones matmul)
-    tri = (_iota((P, P), 0) <= _iota((P, P), 1)).astype(jnp.float32)
-    rank = _fdot(bits, tri)
+    rank = _prefix_count(bits)
     # position of the i-th set bit: rank-one-hot contraction
     hit = (bits[:, None, :]
            * (rank[:, None, :]
@@ -225,9 +238,9 @@ def _unpack_p4_tile(pf, *, wb, k_b, rows):
 
 
 def _unpack_p4_kernel(p_ref, out_ref, *, wb, k_b, rows):
-    off = _unpack_p4_tile(p_ref[0].astype(jnp.float32), wb=wb, k_b=k_b,
-                          rows=rows)
-    out_ref[0] = off.astype(jnp.int32)
+    off = _unpack_p4_tile(p_ref[0, 0].astype(jnp.int32).astype(jnp.float32),
+                          wb=wb, k_b=k_b, rows=rows)
+    out_ref[0, 0] = off.astype(jnp.int32)
 
 
 def _pick_rows(nb: int, per_row_elems: int) -> int:
@@ -241,24 +254,39 @@ def _pick_rows(nb: int, per_row_elems: int) -> int:
     return rows
 
 
+def _tiled_call(kernel, x, rows, outs, interpret):
+    """Run ``kernel`` over (m, nb, n) ``x`` in (rows, n) tiles.
+
+    Arrays are viewed as (m, nb // rows, rows, n), so each block's last
+    two dims are full array dims for any ``rows`` (the TPU block-shape
+    rule).  ``outs``: [(trailing width, dtype)] per output, each returned
+    as (m, nb, width)."""
+    m, nb, n = x.shape
+    g = nb // rows
+    spec = lambda w: pl.BlockSpec((1, 1, rows, w), lambda r, i: (r, i, 0, 0),
+                                  memory_space=pltpu.VMEM)
+    res = pl.pallas_call(
+        kernel,
+        grid=(m, g),
+        in_specs=[spec(n)],
+        out_specs=[spec(w) for w, _ in outs],
+        out_shape=[jax.ShapeDtypeStruct((m, g, rows, w), dt)
+                   for w, dt in outs],
+        interpret=interpret,
+    )(x.reshape(m, g, rows, n))
+    return [r.reshape(m, nb, w) for r, (w, _) in zip(res, outs)]
+
+
 def pack_offsets_pallas(off, *, wb: int, mode: str, interpret=False):
     """off: (m, nb, k_b) int32 sorted ascending -> (m, nb, nbytes) uint8."""
     if mode == "u8":
         return off.astype(jnp.uint8)
     m, nb, k_b = off.shape
     lo_bytes, bm_bytes = _p4_sizes(wb, k_b)
-    nbytes = lo_bytes + bm_bytes
     rows = _pick_rows(nb, k_b * bm_bytes)
-    return pl.pallas_call(
+    return _tiled_call(
         functools.partial(_pack_p4_kernel, wb=wb, k_b=k_b, rows=rows),
-        grid=(m, nb // rows),
-        in_specs=[pl.BlockSpec((1, rows, k_b), lambda r, i: (r, i, 0),
-                               memory_space=pltpu.VMEM)],
-        out_specs=pl.BlockSpec((1, rows, nbytes), lambda r, i: (r, i, 0),
-                               memory_space=pltpu.VMEM),
-        out_shape=jax.ShapeDtypeStruct((m, nb, nbytes), jnp.uint8),
-        interpret=interpret,
-    )(off)
+        off, rows, [(lo_bytes + bm_bytes, jnp.uint8)], interpret)[0]
 
 
 def unpack_offsets_pallas(packed, *, wb: int, k_b: int, mode: str,
@@ -269,21 +297,14 @@ def unpack_offsets_pallas(packed, *, wb: int, k_b: int, mode: str,
     m, nb, nbytes = packed.shape
     _, bm_bytes = _p4_sizes(wb, k_b)
     rows = _pick_rows(nb, k_b * bm_bytes * 8)
-    return pl.pallas_call(
+    return _tiled_call(
         functools.partial(_unpack_p4_kernel, wb=wb, k_b=k_b, rows=rows),
-        grid=(m, nb // rows),
-        in_specs=[pl.BlockSpec((1, rows, nbytes), lambda r, i: (r, i, 0),
-                               memory_space=pltpu.VMEM)],
-        out_specs=pl.BlockSpec((1, rows, k_b), lambda r, i: (r, i, 0),
-                               memory_space=pltpu.VMEM),
-        out_shape=jax.ShapeDtypeStruct((m, nb, k_b), jnp.int32),
-        interpret=interpret,
-    )(packed)
+        packed, rows, [(k_b, jnp.int32)], interpret)[0]
 
 
 def _encode_kernel(x_ref, vals_ref, off_ref, scale_ref, *, wb, k_b, rows,
                    wire_dtype):
-    x = x_ref[0].astype(jnp.float32)  # (rows, wb)
+    x = x_ref[0, 0].astype(jnp.float32)  # (rows, wb)
     mag = jnp.abs(x)
     # fixed-iteration bisection on the magnitude — same loop + invariant
     # as topk_compress._mask_tile (count(mag > lo) > k or lo == 0;
@@ -307,11 +328,11 @@ def _encode_kernel(x_ref, vals_ref, off_ref, scale_ref, *, wb, k_b, rows,
     # order (lo == 0 opens the whole block: an all-/mostly-zero block
     # fills with zeros — exactly k_b survivors always).
     band = jnp.logical_not(primary) & ((mag > lo) | (lo == 0.0))
-    brank = jnp.cumsum(band.astype(jnp.float32), axis=-1)
+    brank = _prefix_count(band.astype(jnp.float32))
     keep = primary | (band & (brank <= k_b - nprim))
     # index-order compaction: the i-th kept element (ascending offset) via
     # the rank one-hot — offsets come out SORTED natively.
-    krank = jnp.cumsum(keep.astype(jnp.float32), axis=-1)
+    krank = _prefix_count(keep.astype(jnp.float32))
     hit = (keep[:, None, :]
            & (krank[:, None, :]
               == (_iota((rows, k_b, wb), 1) + 1).astype(jnp.float32)))
@@ -319,16 +340,17 @@ def _encode_kernel(x_ref, vals_ref, off_ref, scale_ref, *, wb, k_b, rows,
     off = (hitf * _iota((rows, k_b, wb), 2).astype(jnp.float32)).sum(axis=-1)
     vals = (hitf * x[:, None, :]).sum(axis=-1)  # (rows, k_b)
     scale = hi0  # block max |x|: the max element is always kept
-    off_ref[0] = off.astype(jnp.int32)
-    scale_ref[0] = scale[:, 0]
+    off_ref[0, 0] = off.astype(jnp.int32)
+    scale_ref[0, 0] = scale
     if wire_dtype in ("f32", "bf16"):
-        vals_ref[0] = vals.astype(vals_ref.dtype)
+        vals_ref[0, 0] = vals.astype(vals_ref.dtype)
         return
     r = vals / jnp.maximum(scale, 1e-30)
     if wire_dtype == "int8":
-        vals_ref[0] = jnp.round(r * 127.0).astype(jnp.int8)
+        vals_ref[0, 0] = jnp.round(r * 127.0).astype(jnp.int32).astype(
+            jnp.int8)
     elif wire_dtype == "fp8":
-        vals_ref[0] = jax.lax.bitcast_convert_type(
+        vals_ref[0, 0] = jax.lax.bitcast_convert_type(
             r.astype(jnp.float8_e4m3fn), jnp.uint8)
     else:  # int4: two's-complement nibbles packed two per byte
         q = jnp.round(r * 7.0)
@@ -337,7 +359,7 @@ def _encode_kernel(x_ref, vals_ref, off_ref, scale_ref, *, wb, k_b, rows,
         t = _iota((k_b, k_pairs), 0)
         j = _iota((k_b, k_pairs), 1)
         nib = ((t == 2 * j) + 16 * (t == 2 * j + 1)).astype(jnp.float32)
-        vals_ref[0] = _fdot(q, nib).astype(jnp.uint8)
+        vals_ref[0, 0] = _to_u8(_fdot(q, nib))
 
 
 def encode_blocks_pallas(xb, k_b: int, *, wire_dtype: str, interpret=False):
@@ -350,19 +372,9 @@ def encode_blocks_pallas(xb, k_b: int, *, wire_dtype: str, interpret=False):
                  "int8": jnp.int8}.get(wire_dtype, jnp.uint8)
     k_out = -(-k_b // 2) if wire_dtype == "int4" else k_b
     rows = _pick_rows(nb, k_b * wb)
-    tile = lambda n: pl.BlockSpec((1, rows, n), lambda r, i: (r, i, 0),
-                                  memory_space=pltpu.VMEM)
-    vals, off, scale = pl.pallas_call(
+    vals, off, scale = _tiled_call(
         functools.partial(_encode_kernel, wb=wb, k_b=k_b, rows=rows,
                           wire_dtype=wire_dtype),
-        grid=(m, nb // rows),
-        in_specs=[tile(wb)],
-        out_specs=[tile(k_out), tile(k_b),
-                   pl.BlockSpec((1, rows), lambda r, i: (r, i),
-                                memory_space=pltpu.VMEM)],
-        out_shape=[jax.ShapeDtypeStruct((m, nb, k_out), val_dtype),
-                   jax.ShapeDtypeStruct((m, nb, k_b), jnp.int32),
-                   jax.ShapeDtypeStruct((m, nb), jnp.float32)],
-        interpret=interpret,
-    )(xb)
-    return vals, off, scale
+        xb, rows, [(k_out, val_dtype), (k_b, jnp.int32), (1, jnp.float32)],
+        interpret)
+    return vals, off, scale[..., 0]

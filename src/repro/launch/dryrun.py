@@ -1,6 +1,12 @@
 import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
-# ^ MUST precede every other import (jax locks device count on first init).
+# MUST precede every other import (jax locks its platform and device count
+# on first init).  This AOT tool compiles on 512 fake CPU devices and never
+# takes an accelerator, for itself or the cell subprocesses it spawns (they
+# inherit the environment): a chip belongs to one process at a time.
+os.environ["JAX_PLATFORMS"] = "cpu"
+os.environ["XLA_FLAGS"] = (os.environ.get("XLA_FLAGS", "") +
+                           " --xla_force_host_platform_device_count=512"
+                           ).strip()
 
 import argparse
 import dataclasses

@@ -19,3 +19,10 @@ def dp_axes(mesh) -> tuple:
 def make_host_mesh():
     """1-device mesh for CPU tests (policy plumbing without sharding)."""
     return make_mesh((1, 1), ("data", "model"))
+
+
+def make_local_mesh():
+    """All of this host's devices as (n, 1) ("data", "model"): one FL
+    replica slot per chip (1 on a one-chip host, 4 on a 2x2 v5e host)."""
+    import jax
+    return make_mesh((len(jax.devices()), 1), ("data", "model"))

@@ -20,6 +20,7 @@ import numpy as np
 
 from repro.configs import ARCH_IDS, get_config, smoke_model
 from repro.dist.policies import make_serve_policy
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import dp_axes, make_production_mesh
 from repro.models.registry import get_model
 from repro.serving.engine import Engine, PagedConfig, ServeConfig
@@ -48,9 +49,16 @@ def _serve_continuous(engine, args, vocab):
         o = outs[rid]
         print(f"  req{rid}: ttft={o.ttft*1e3:.1f}ms "
               f"tokens={o.tokens[:8]}{'...' if len(o.tokens) > 8 else ''}")
+    return {"requests": reqs, "outputs": outs, "seconds": dt}
 
 
-def main():
+def main(argv=None):
+    run(argv)
+
+
+def run(argv=None) -> dict:
+    """Parse ``argv``, build the engine and serve; returns the engine and
+    what it produced (chip_smoke.py drives it)."""
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="qwen2_7b", choices=ARCH_IDS)
     ap.add_argument("--mesh", default="host",
@@ -70,7 +78,8 @@ def main():
                     help="stream length for --continuous")
     ap.add_argument("--rate", type=float, default=100.0,
                     help="Poisson arrival rate (req/s) for --continuous")
-    args = ap.parse_args()
+    args = ap.parse_args(argv)
+    enable_compile_cache()
 
     bundle = get_config(args.arch)
     cfg = smoke_model(bundle.model) if args.smoke else bundle.model
@@ -90,8 +99,8 @@ def main():
                                       max_slots=args.batch,
                                       kv_dtype=args.kv_dtype))
     if args.continuous:
-        _serve_continuous(engine, args, cfg.vocab_size)
-        return
+        return dict(_serve_continuous(engine, args, cfg.vocab_size),
+                    engine=engine)
     rng = np.random.default_rng(0)
     prompts = rng.integers(0, cfg.vocab_size,
                            (args.batch, args.prompt_len)).astype(np.int32)
@@ -111,6 +120,7 @@ def main():
           f"({n_tok/dt:.1f} tok/s on this backend)")
     for i, row in enumerate(out[:4]):
         print(f"  req{i}: {row[:12].tolist()}")
+    return {"engine": engine, "tokens": out, "seconds": dt}
 
 
 if __name__ == "__main__":

@@ -3,12 +3,17 @@
     PYTHONPATH=src python -m repro.launch.train --arch smollm_135m \
         --mesh host --smoke --rounds 8 --controller hcef
 
---mesh host   : single-device (CPU) run, reduced config unless --full.
+--mesh host   : single-device run (R=4 stacked replicas on one device, no
+                mesh); reduced config unless --full.
+--mesh local  : mesh over this host's devices, (n, 1) as ("data", "model"),
+                R=4 as 2 clusters x 2 tiling it (one replica per chip on a
+                four-chip host).
 --mesh single : 16x16 production mesh (on TPU hardware; on CPU this requires
                 xla_force_host_platform_device_count and is what
                 launch/dryrun.py exercises AOT).
 Ties together: mesh + policy + HCEF round steps + online controller +
-heterogeneity/budget accounting + checkpointing.
+heterogeneity/budget accounting + checkpointing.  ``run`` returns the
+losses, timings and compiled programs (chip_smoke.py drives it).
 """
 from __future__ import annotations
 
@@ -33,18 +38,62 @@ from repro.fl.baselines import make_controller
 from repro.fl.cost_model import (decide_stale_clusters, overlap_round_time,
                                  per_device_energy, round_energy, round_time)
 from repro.fl.heterogeneity import HeterogeneityModel
-from repro.launch.mesh import dp_axes, make_production_mesh
+from repro.launch.compile_cache import enable_compile_cache
+from repro.launch.mesh import (dp_axes, make_local_mesh,
+                               make_production_mesh)
 from repro.runtime.checkpoint import save_pytree
 from repro.runtime.chaos import ChaosConfig, FaultPlan, controls_on_live
 from repro.runtime.elastic import cohort_swap
 from repro.runtime.population import PopulationStore
 
 
-def main():
+def make_topology(mesh_kind: str, bundle):
+    """(mesh, topo, policy) for a --mesh choice."""
+    from repro.configs.base import FLTopology
+    if mesh_kind == "host":
+        return None, FLTopology(clusters=2, devices_per_cluster=2), None
+    if mesh_kind == "local":
+        mesh = make_local_mesh()
+        topo = FLTopology(clusters=2, devices_per_cluster=2)
+    else:
+        mesh = make_production_mesh(multi_pod=mesh_kind == "multi")
+        topo = bundle.fl_multi if mesh_kind == "multi" else bundle.fl_single
+    return mesh, topo, make_train_policy(mesh, topo, dp_axes=dp_axes(mesh))
+
+
+def init_placed_state(cfg, hcef, topo, policy, seed: int = 0):
+    """Round-0 state, built in place: with a policy every leaf is created
+    directly in its policy sharding (nothing is built whole on one chip
+    first)."""
+    init = init_overlap_state if hcef.overlap else init_state
+    make = lambda: init(cfg, hcef, topo, jax.random.PRNGKey(seed))
+    if policy is None:
+        return jax.jit(make)()
+    shd = policy.param_shardings(jax.eval_shape(make), stacked=True)
+    return jax.jit(make, out_shardings=shd)()
+
+
+def compile_step(step, args):
+    """AOT-compile a round step for ``args`` with the state (argument 0)
+    donated.  Returns (compiled, seconds)."""
+    t0 = time.perf_counter()
+    compiled = jax.jit(step, donate_argnums=0).lower(*args).compile()
+    return compiled, time.perf_counter() - t0
+
+
+def _gb(n):
+    return f"{n / 2**30:.2f}GiB"
+
+
+def main(argv=None):
+    run(argv)
+
+
+def run(argv=None) -> dict:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="smollm_135m", choices=ARCH_IDS)
     ap.add_argument("--mesh", default="host",
-                    choices=["host", "single", "multi"])
+                    choices=["host", "local", "single", "multi"])
     ap.add_argument("--smoke", action="store_true", default=True)
     ap.add_argument("--full", dest="smoke", action="store_false")
     ap.add_argument("--rounds", type=int, default=8)
@@ -90,7 +139,8 @@ def main():
     ap.add_argument("--chaos-partition", type=float, default=0.1)
     ap.add_argument("--chaos-coord-fail", type=float, default=0.2)
     ap.add_argument("--chaos-seed", type=int, default=0)
-    args = ap.parse_args()
+    args = ap.parse_args(argv)
+    enable_compile_cache()
 
     bundle = get_config(args.arch)
     cfg = smoke_model(bundle.model) if args.smoke else bundle.model
@@ -104,21 +154,12 @@ def main():
             overlap=args.overlap,
             staleness=args.staleness if args.overlap else 0)
 
-    if args.mesh == "host":
-        mesh, policy = None, None
-        from repro.configs.base import FLTopology
-        topo = FLTopology(clusters=2, devices_per_cluster=2)
-    else:
-        mesh = make_production_mesh(multi_pod=args.mesh == "multi")
-        topo = bundle.fl_multi if args.mesh == "multi" else bundle.fl_single
-        policy = make_train_policy(mesh, topo, dp_axes=dp_axes(mesh))
+    mesh, topo, policy = make_topology(args.mesh, bundle)
 
     R = topo.num_devices
     cluster_of = np.repeat(np.arange(topo.clusters),
                            topo.devices_per_cluster)
-    state = (init_overlap_state(cfg, hcef, topo, jax.random.PRNGKey(0))
-             if hcef.overlap
-             else init_state(cfg, hcef, topo, jax.random.PRNGKey(0)))
+    state = init_placed_state(cfg, hcef, topo, policy)
     # Per-assignment jit cache (DESIGN.md §Static-k): gossip steps are
     # keyed by the static per-cluster level assignment so each distinct
     # (cluster -> level) vector lowers ONE program with sender-sized
@@ -128,12 +169,18 @@ def main():
     # genuinely new assignment, not of revisiting a recent one).  The
     # overlapped engine adds the static stale-cluster set to the key
     # (DESIGN.md §Overlap) — one program per (levels, stale) assignment.
+    # Programs are compiled ahead of time for the call's arguments (the
+    # state donated), so each new one's memory analysis is printed before
+    # it first runs; the key also holds the argument shardings and the
+    # call's arity (chaos rounds pass the fault masks).
     step_cache: OrderedDict = OrderedDict()
     STEP_CACHE_MAX = 32
 
-    def get_step(gossip_round: bool, cluster_levels=None,
+    def get_step(call_args, gossip_round: bool, cluster_levels=None,
                  stale_clusters=None):
-        key = (gossip_round, cluster_levels, stale_clusters)
+        key = (gossip_round, cluster_levels, stale_clusters,
+               tuple(x.sharding for x in jax.tree.leaves(call_args)))
+        secs = 0.0
         if key not in step_cache:
             if hcef.overlap:
                 step = make_overlap_round_step(
@@ -144,11 +191,18 @@ def main():
                 step = make_round_step(
                     cfg, hcef, topo, policy, gossip=gossip_round,
                     cluster_levels=cluster_levels)
-            step_cache[key] = jax.jit(step)
+            compiled, secs = compile_step(step, call_args)
+            ma = compiled.memory_analysis()
+            print(f"compiled step gossip={gossip_round} in {secs:.1f}s: "
+                  f"args={_gb(ma.argument_size_in_bytes)} "
+                  f"out={_gb(ma.output_size_in_bytes)} "
+                  f"temp={_gb(ma.temp_size_in_bytes)} "
+                  f"alias={_gb(ma.alias_size_in_bytes)}", flush=True)
+            step_cache[key] = compiled
             if len(step_cache) > STEP_CACHE_MAX:
                 step_cache.popitem(last=False)
         step_cache.move_to_end(key)
-        return step_cache[key]
+        return step_cache[key], secs
 
     controller = make_controller(args.controller, hcef.tau)
     fl0 = state.fl if hcef.overlap else state
@@ -212,6 +266,8 @@ def main():
 
     print(f"arch={args.arch} mesh={args.mesh} R={R} controller="
           f"{args.controller} params/replica={n_params:,}")
+    result = {"losses": [], "step_s": [], "compile_s": [], "gossip": [],
+              "programs": step_cache}
     ctx = mesh or _null()
     with ctx:
         for rnd in range(args.rounds):
@@ -299,24 +355,33 @@ def main():
                         **wire_kw),
                     alive=alive0)
                 alive, conn = faults.alive, faults.cluster_conn
-            fn = get_step(gossip_round, cluster_levels, stale_cl)
             degraded = faults is not None and (not alive.all()
                                                or not conn.all())
+            call_args = (state, batch, jnp.asarray(rho, jnp.float32),
+                         jnp.asarray(theta, jnp.float32), keys)
+            # fault-free rounds take the EXACT unmasked trace (bitwise
+            # contract: chaos at zero faults == no chaos).
             if degraded:
                 from repro.dist.collectives import participation_weights
                 aw = participation_weights(
                     alive, clusters=topo.clusters,
                     dev=topo.devices_per_cluster)
-                state, m = fn(state, batch, jnp.asarray(rho, jnp.float32),
-                              jnp.asarray(theta, jnp.float32), keys,
-                              jnp.asarray(alive, jnp.float32),
+                call_args += (jnp.asarray(alive, jnp.float32),
                               jnp.asarray(aw, jnp.float32),
                               jnp.asarray(conn, jnp.float32))
-            else:
-                # fault-free rounds take the EXACT unmasked trace (bitwise
-                # contract: chaos at zero faults == no chaos).
-                state, m = fn(state, batch, jnp.asarray(rho, jnp.float32),
-                              jnp.asarray(theta, jnp.float32), keys)
+            fn, csecs = get_step(call_args, gossip_round, cluster_levels,
+                                 stale_cl)
+            del state  # donated to the step
+            t_step = time.perf_counter()
+            state, m = fn(*call_args)
+            del call_args
+            jax.block_until_ready(state)
+            step_s = time.perf_counter() - t_step
+            loss = float(m["loss"].mean())
+            result["losses"].append(loss)
+            result["step_s"].append(step_s)
+            result["compile_s"].append(csecs)
+            result["gossip"].append(gossip_round)
             if stale_cl:
                 # overlapped accounting: a stale cluster's gossip transfer
                 # hides behind its tau local steps — max, not sum.
@@ -361,10 +426,11 @@ def main():
                              f"coord={faults.coordinator}"
                              + (f" cut={int((~faults.cluster_conn).sum())}"
                                 if not faults.cluster_conn.all() else ""))
-            print(f"round {rnd:3d} loss={float(m['loss'].mean()):7.4f} "
+            print(f"round {rnd:3d} loss={loss:7.4f} "
                   f"rho={np.mean(rho):.2f} theta={np.mean(theta):.2f} "
                   f"sim_t={budget.time_spent_prev + budget.time_spent_this:9.0f}s "
-                  f"wall={time.time()-t0:5.1f}s" + chaos_str)
+                  f"wall={time.time()-t0:5.1f}s step={step_s:.3f}s"
+                  + chaos_str, flush=True)
             if args.ckpt_dir:
                 fl = state.fl if hcef.overlap else state
                 meta = {"round": rnd}
@@ -374,6 +440,8 @@ def main():
                                    / f"ckpt_{rnd:06d}.pop.npz")
                 save_pytree(Path(args.ckpt_dir) / f"ckpt_{rnd:06d}.npz",
                             fl._asdict(), meta=meta)
+    result["state"] = state
+    return result
 
 
 class _null:

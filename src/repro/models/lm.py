@@ -107,7 +107,8 @@ def _attention(cfg, x, w, pol, positions, *, causal, window=0, prefix=""):
     q = constrain(pol, q, "heads")
     k = constrain(pol, k, "kv_full")  # gather over the sequence-shard axis
     v = constrain(pol, v, "kv_full")
-    o = ops.flash_attention(q, k, v, causal=causal, window=window)
+    o = ops.flash_attention(q, k, v, causal=causal, window=window,
+                            policy=pol)
     o = constrain(pol, o, "heads")
     o = o.reshape(B, S, H * Dh) @ w[prefix + "wo"]
     return constrain(pol, o, "residual"), (k, v)
@@ -120,7 +121,7 @@ def _cross_attention(cfg, x, w, pol, mem_kv):
     q = (x @ w["wxq"]).astype(cd).reshape(B, S, H, Dh)
     k, v = mem_kv  # (B, S_enc, KH, Dh) each, precomputed from encoder output
     q = constrain(pol, q, "heads")
-    o = ops.flash_attention(q, k, v, causal=False)
+    o = ops.flash_attention(q, k, v, causal=False, policy=pol)
     o = o.reshape(B, S, H * Dh) @ w["wxo"]
     return constrain(pol, o, "residual")
 

@@ -75,6 +75,37 @@ def test_sharded_round_matches_unsharded(gossip):
         assert err < 5e-3, (str(kp), err)
 
 
+@pytest.mark.parametrize("gossip", [True, False])
+def test_sharded_round_pallas_attention_matches_unsharded(gossip):
+    """On a mesh the Pallas flash kernel runs inside a shard_map over the
+    policy's attention specs (Mosaic kernels cannot be partitioned
+    automatically); heads split over the model axis here.  Interpret mode,
+    same bounds as the jnp route above."""
+    from repro.kernels import ops
+    cfg, topo, hcef, state, batch, keys = _setup()
+    R = topo.num_devices
+    rho, theta = jnp.ones(R), jnp.full(R, 0.25)
+    mesh = make_mesh((4, 2), ("data", "model"))
+    policy = make_train_policy(mesh, topo, dp_axes=("data",))
+    with ops.forced_route("pallas"):
+        step0 = jax.jit(make_round_step(cfg, hcef, topo, policy=None,
+                                        gossip=gossip))
+        step1 = jax.jit(make_round_step(cfg, hcef, topo, policy=policy,
+                                        gossip=gossip))
+        s_ref, m_ref = step0(state, batch, rho, theta, keys)
+        shd = policy.param_shardings(state, stacked=True)
+        with mesh:
+            s_sh, m_sh = step1(jax.device_put(state, shd), batch, rho,
+                               theta, keys)
+    assert abs(float(m_ref["loss"].mean()) - float(m_sh["loss"].mean())) \
+        < 1e-3
+    for a, b in zip(jax.tree.leaves((s_ref.params, s_ref.ef)),
+                    jax.tree.leaves((s_sh.params, s_sh.ef))):
+        err = float(jnp.abs(jnp.asarray(a, jnp.float32)
+                            - jnp.asarray(b, jnp.float32)).max())
+        assert err < 5e-3, err
+
+
 def test_fused_path_emits_no_full_leaf_allgather():
     """The compiled round step must never re-materialize a model-sharded
     leaf: all aggregation traffic is shard-sized (collective-permute/psum),
