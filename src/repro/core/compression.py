@@ -71,38 +71,39 @@ def compress_delta(delta, ef, theta, *, block: int = 1024,
     the leaves (including the leading R dim) -> shard_map per-shard path.
     replica_spec: PartitionSpec for the (R,) theta vector.
     """
-    if mesh is None or specs is None:
-        fn = functools.partial(_leaf_plain, theta=theta, block=block,
-                               error_feedback=error_feedback, impl=impl)
+    with jax.named_scope("hcef.compress"):
+        if mesh is None or specs is None:
+            fn = functools.partial(_leaf_plain, theta=theta, block=block,
+                                   error_feedback=error_feedback, impl=impl)
+            flat_d, treedef = jax.tree.flatten(delta)
+            flat_e = (treedef.flatten_up_to(ef) if ef is not None
+                      else [None] * len(flat_d))
+            out = [fn(d, e) for d, e in zip(flat_d, flat_e)]
+            return (treedef.unflatten([m for m, _ in out]),
+                    treedef.unflatten([r for _, r in out]))
+
+        rspec = replica_spec if replica_spec is not None else P(None)
+
+        def per_leaf(d, e, spec):
+            def local(dl, el, tl):
+                Rl = dl.shape[0]
+                flat = dl.reshape(Rl, -1)
+                ef = el.reshape(Rl, -1) if error_feedback else None
+                masked, resid = _compress_flat(flat, tl, block, impl, ef=ef)
+                return (masked.reshape(dl.shape).astype(dl.dtype),
+                        resid.reshape(dl.shape).astype(el.dtype))
+
+            fn = shard_map(local, mesh=mesh, in_specs=(spec, spec, rspec),
+                           out_specs=(spec, spec), check_vma=False)
+            return fn(d, e if e is not None else jnp.zeros_like(d), theta)
+
         flat_d, treedef = jax.tree.flatten(delta)
         flat_e = (treedef.flatten_up_to(ef) if ef is not None
                   else [None] * len(flat_d))
-        out = [fn(d, e) for d, e in zip(flat_d, flat_e)]
+        flat_s = treedef.flatten_up_to(specs)
+        out = [per_leaf(d, e, s) for d, e, s in zip(flat_d, flat_e, flat_s)]
         return (treedef.unflatten([m for m, _ in out]),
                 treedef.unflatten([r for _, r in out]))
-
-    rspec = replica_spec if replica_spec is not None else P(None)
-
-    def per_leaf(d, e, spec):
-        def local(dl, el, tl):
-            Rl = dl.shape[0]
-            flat = dl.reshape(Rl, -1)
-            ef = el.reshape(Rl, -1) if error_feedback else None
-            masked, resid = _compress_flat(flat, tl, block, impl, ef=ef)
-            return (masked.reshape(dl.shape).astype(dl.dtype),
-                    resid.reshape(dl.shape).astype(el.dtype))
-
-        fn = shard_map(local, mesh=mesh, in_specs=(spec, spec, rspec),
-                       out_specs=(spec, spec), check_vma=False)
-        return fn(d, e if e is not None else jnp.zeros_like(d), theta)
-
-    flat_d, treedef = jax.tree.flatten(delta)
-    flat_e = (treedef.flatten_up_to(ef) if ef is not None
-              else [None] * len(flat_d))
-    flat_s = treedef.flatten_up_to(specs)
-    out = [per_leaf(d, e, s) for d, e, s in zip(flat_d, flat_e, flat_s)]
-    return (treedef.unflatten([m for m, _ in out]),
-            treedef.unflatten([r for _, r in out]))
 
 
 # Bits per kept entry of the FIXED-WIDTH v1 wire formats: (value_bits,

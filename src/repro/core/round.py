@@ -217,30 +217,39 @@ def make_round_step(cfg: ModelConfig, hcef: HCEFConfig, topo: FLTopology,
     def device_round(params, mom, batch_tau, key, rho_r):
         """One device's tau local iterations. All args UNSTACKED."""
         x0 = params
-        bits = jax.random.bernoulli(
-            key, jnp.clip(rho_r, 0.0, 1.0), (hcef.tau,)).astype(jnp.float32)
+        with jax.named_scope("hcef.grad_stats"):
+            bits = jax.random.bernoulli(
+                key, jnp.clip(rho_r, 0.0, 1.0),
+                (hcef.tau,)).astype(jnp.float32)
 
         def step(carry, inp):
             p, m = carry
             batch_s, bit = inp
             loss, g = jax.value_and_grad(
                 lambda pp: model.loss_fn(cfg, pp, batch_s, policy))(p)
-            gn2 = _global_norm2(g)
-            g = jax.tree.map(lambda a: a * bit.astype(a.dtype), g)
-            p, m = sgd_update(p, g, m, lr=hcef.eta, momentum=hcef.momentum)
+            with jax.named_scope("hcef.grad_stats"):
+                gn2 = _global_norm2(g)
+                g = jax.tree.map(lambda a: a * bit.astype(a.dtype), g)
+            with jax.named_scope("hcef.sgd"):
+                p, m = sgd_update(p, g, m, lr=hcef.eta, momentum=hcef.momentum)
             return (p, m), (loss, gn2, bit)
 
-        (params, mom), (losses, gn2s, bits_out) = jax.lax.scan(
-            step, (params, mom), (batch_tau, bits))
-        delta = jax.tree.map(
-            lambda a, b: (a.astype(jnp.float32)
-                          - b.astype(jnp.float32)).astype(a.dtype),
-            params, x0)
+        # the scope covers the loop as well as its body: the loop's carry,
+        # the zeros autodiff makes for cotangents, the stacked outputs
+        with jax.named_scope("hcef.local_step"):
+            (params, mom), (losses, gn2s, bits_out) = jax.lax.scan(
+                step, (params, mom), (batch_tau, bits))
+        with jax.named_scope("hcef.delta"):
+            delta = jax.tree.map(
+                lambda a, b: (a.astype(jnp.float32)
+                              - b.astype(jnp.float32)).astype(a.dtype),
+                params, x0)
         # Algorithm-2 style statistics (norm-based proxies; DESIGN.md):
-        g2_est = jnp.min(gn2s)
-        sigma2_est = jnp.maximum(jnp.mean(gn2s) - g2_est, 0.0)
-        metrics = {"loss": jnp.mean(losses), "g2": g2_est,
-                   "sigma2": sigma2_est, "steps": jnp.sum(bits_out)}
+        with jax.named_scope("hcef.grad_stats"):
+            g2_est = jnp.min(gn2s)
+            sigma2_est = jnp.maximum(jnp.mean(gn2s) - g2_est, 0.0)
+            metrics = {"loss": jnp.mean(losses), "g2": g2_est,
+                       "sigma2": sigma2_est, "steps": jnp.sum(bits_out)}
         return delta, mom, metrics
 
     spmd = tuple(policy.replica_axes) if (
@@ -345,9 +354,10 @@ def make_round_step(cfg: ModelConfig, hcef: HCEFConfig, topo: FLTopology,
                     flat = ds.reshape(Rl, -1)
                     ef_flat = (es.reshape(Rl, -1) if hcef.error_feedback
                                else None)
-                    masked, resid = _compress_flat(flat, ts,
-                                                   hcef.block_size, impl,
-                                                   ef=ef_flat)
+                    with jax.named_scope("hcef.compress"):
+                        masked, resid = _compress_flat(flat, ts,
+                                                       hcef.block_size,
+                                                       impl, ef=ef_flat)
                     mix_kw = {}
                     if chaos:
                         # EF conservation fold: a dropped device's split is
@@ -359,13 +369,15 @@ def make_round_step(cfg: ModelConfig, hcef: HCEFConfig, topo: FLTopology,
                             jnp.where(a, resid, masked + resid))
                         mix_kw = dict(alive=cargs[1],
                                       conn=cargs[2] if pass_conn else None)
-                    upd = x0s + masked.reshape(ds.shape).astype(x0s.dtype)
                     # rep_axes == () with R > 1 means the replica dim is
                     # fully replicated per shard; mix_local then runs the
                     # dense-local factorization — never skip W silently.
-                    y = mix_local(upd, clusters=C, dev=Dev, axes=rep_axes,
-                                  hkind=mix_hkind, **mix_kw) if R > 1 \
-                        else upd
+                    with jax.named_scope("hcef.aggregate"):
+                        upd = x0s + masked.reshape(ds.shape).astype(
+                            x0s.dtype)
+                        y = mix_local(upd, clusters=C, dev=Dev,
+                                      axes=rep_axes, hkind=mix_hkind,
+                                      **mix_kw) if R > 1 else upd
                     return (y.astype(x0s.dtype),
                             resid.reshape(es.shape).astype(es.dtype))
 
@@ -421,10 +433,12 @@ def make_round_step(cfg: ModelConfig, hcef: HCEFConfig, topo: FLTopology,
                                               else ())
                     gargs = (ml,) + (tuple(ef) if ef else ()) + (
                         (conn_f,) if gossip_conn else ())
-                    return shard_map(local_g, mesh=mesh, in_specs=gspecs,
-                                     out_specs=(spec,) * nio if ef
-                                     else spec,
-                                     check_vma=False)(*gargs)
+                    with jax.named_scope("hcef.gossip"):
+                        return shard_map(local_g, mesh=mesh,
+                                         in_specs=gspecs,
+                                         out_specs=(spec,) * nio if ef
+                                         else spec,
+                                         check_vma=False)(*gargs)
 
                 if use_wef:
                     outs = [gossip_leaf_pc(m, s, (es, ew))
@@ -472,10 +486,12 @@ def make_round_step(cfg: ModelConfig, hcef: HCEFConfig, topo: FLTopology,
                                               else ())
                     gargs = (ml,) + (tuple(ef) if ef else ()) + (
                         (conn_f,) if gossip_conn else ())
-                    return shard_map(local_g, mesh=mesh, in_specs=gspecs,
-                                     out_specs=(spec,) * nio if ef
-                                     else spec,
-                                     check_vma=False)(*gargs)
+                    with jax.named_scope("hcef.gossip"):
+                        return shard_map(local_g, mesh=mesh,
+                                         in_specs=gspecs,
+                                         out_specs=(spec,) * nio if ef
+                                         else spec,
+                                         check_vma=False)(*gargs)
 
                 if use_wef:
                     def branch(level):
@@ -519,13 +535,14 @@ def make_round_step(cfg: ModelConfig, hcef: HCEFConfig, topo: FLTopology,
             # Under chaos the same GEMM absorbs the whole degraded-mode
             # contract: H -> participation_mixing(H, conn) and diag(1/Dev)
             # -> diag(alive_w/Dev) (the live-count-renormalized mean).
-            Hg = H
-            if chaos and conn is not None and gossip:
-                Hg = mixing.participation_mixing(H, conn_f).astype(
-                    jnp.float32)
-            M = jnp.repeat(Hg / Dev, Dev, axis=1)  # (C, R)
-            if chaos:
-                M = M * alive_wf[None, :]
+            with jax.named_scope("hcef.aggregate"):
+                Hg = H
+                if chaos and conn is not None and gossip:
+                    Hg = mixing.participation_mixing(H, conn_f).astype(
+                        jnp.float32)
+                M = jnp.repeat(Hg / Dev, Dev, axis=1)  # (C, R)
+                if chaos:
+                    M = M * alive_wf[None, :]
 
             def aggregate(x0_leaf, comp_leaf):
                 upd = (x0_leaf.astype(jnp.float32)
@@ -544,7 +561,8 @@ def make_round_step(cfg: ModelConfig, hcef: HCEFConfig, topo: FLTopology,
                         yc[:, None], (C, Dev) + dims).reshape(upd.shape)
                 return upd.astype(x0_leaf.dtype)
 
-            new_params = jax.tree.map(aggregate, state.params, comp)
+            with jax.named_scope("hcef.aggregate"):
+                new_params = jax.tree.map(aggregate, state.params, comp)
         new_state = FLState(params=new_params, momentum=mom, ef=ef,
                             round_idx=state.round_idx + 1,
                             wire_ef=new_wef)
@@ -666,8 +684,10 @@ def make_overlap_round_step(cfg: ModelConfig, hcef: HCEFConfig,
                 gspecs = (spec, spec) + ((PS(None),) if gossip_conn
                                          else ())
                 gargs = (ml, pl) + ((conn_f,) if gossip_conn else ())
-                return shard_map(local_g, mesh=mesh, in_specs=gspecs,
-                                 out_specs=spec, check_vma=False)(*gargs)
+                with jax.named_scope("hcef.gossip"):
+                    return shard_map(local_g, mesh=mesh, in_specs=gspecs,
+                                     out_specs=spec,
+                                     check_vma=False)(*gargs)
 
             if cluster_levels is not None or not sparse:
                 new_flat = [gossip_leaf(m, p, s, 1.0)
@@ -697,13 +717,14 @@ def make_overlap_round_step(cfg: ModelConfig, hcef: HCEFConfig,
         else:
             # off-mesh: dense fold through the same stale-select operator
             # (theta=1.0 f32 wire ships the dense rows bit-exactly).
-            new_params = jax.tree.map(
-                lambda ml, pl: sparse_neighbor_exchange(
-                    ml, clusters=C, dev=Dev, axes=(), hkind=hkind,
-                    theta=1.0, intra_done=True, stale=pl,
-                    stale_clusters=stale_clusters, conn=conn_f,
-                    wire_dtype="f32"),
-                fl_mid.params, state.pending)
+            with jax.named_scope("hcef.gossip"):
+                new_params = jax.tree.map(
+                    lambda ml, pl: sparse_neighbor_exchange(
+                        ml, clusters=C, dev=Dev, axes=(), hkind=hkind,
+                        theta=1.0, intra_done=True, stale=pl,
+                        stale_clusters=stale_clusters, conn=conn_f,
+                        wire_dtype="f32"),
+                    fl_mid.params, state.pending)
         metrics["stale_frac"] = jnp.float32(len(stale_clusters) / C)
         fl = FLState(params=new_params, momentum=fl_mid.momentum,
                      ef=fl_mid.ef, round_idx=fl_mid.round_idx,

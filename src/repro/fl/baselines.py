@@ -22,6 +22,12 @@ class Controller:
         self.diag: dict = {}
 
     def controls(self, reports: DeviceReports, budget: BudgetState):
+        """(rho, theta) for this round; the solve is traced as the host
+        span ``hcef.controller``."""
+        with jax.profiler.TraceAnnotation("hcef.controller"):
+            return self.solve(reports, budget)
+
+    def solve(self, reports: DeviceReports, budget: BudgetState):
         raise NotImplementedError
 
 
@@ -29,7 +35,7 @@ class HCEF(Controller):
     """Joint adaptive rho & theta (Algorithm 3)."""
     name = "hcef"
 
-    def controls(self, reports, budget):
+    def solve(self, reports, budget):
         self.diag = {}
         return solve_p2(reports, budget, self.tau, self.theta_min,
                         self.rho_min, diagnostics=self.diag)
@@ -39,7 +45,7 @@ class CEF(Controller):
     """CE-FedAvg: heterogeneity-oblivious (rho = theta = 1)."""
     name = "cef"
 
-    def controls(self, reports, budget):
+    def solve(self, reports, budget):
         N = len(reports.mu)
         return np.ones(N), np.ones(N)
 
@@ -48,7 +54,7 @@ class CEF_F(Controller):
     """Adaptive local update frequency only (theta = 1)."""
     name = "cef_f"
 
-    def controls(self, reports, budget):
+    def solve(self, reports, budget):
         self.diag = {}
         return solve_p2(reports, budget, self.tau, self.theta_min,
                         self.rho_min, fix_theta=1.0,
@@ -59,7 +65,7 @@ class CEF_C(Controller):
     """Adaptive compression only (rho = 1)."""
     name = "cef_c"
 
-    def controls(self, reports, budget):
+    def solve(self, reports, budget):
         self.diag = {}
         return solve_p2(reports, budget, self.tau, self.theta_min,
                         self.rho_min, fix_rho=1.0,
@@ -73,7 +79,7 @@ class MLL_SGD(Controller):
     so the baseline is competitive, as in the original MLL-SGD.)"""
     name = "mll_sgd"
 
-    def controls(self, reports, budget):
+    def solve(self, reports, budget):
         inv = 1.0 / np.maximum(reports.mu, 1e-12)
         rho = inv / inv.max()
         return np.clip(rho, self.rho_min, 1.0), np.ones(len(rho))

@@ -25,6 +25,7 @@ from __future__ import annotations
 
 from dataclasses import dataclass
 
+import jax
 import numpy as np
 
 from repro.core.controller import DeviceReports
@@ -69,38 +70,40 @@ class HeterogeneityModel:
         Dynamic state is drawn population-wide from the (seed, round)
         stream and indexed, so a client's round-r report is the same no
         matter which cohort it lands in."""
-        rng = np.random.default_rng(
-            np.random.SeedSequence([self.seed, round_idx]))
-        N = self.population_size
-        if self.profile == "paper_edge":
-            # dynamic U(1, 2) GHz throttle on top of the persistent
-            # capability: a cap-0.5 phone spans [0.5, 1] GHz effective,
-            # a cap-1.0 phone [1, 2] GHz — persistent speed identity
-            # (the paper's U(1, 2)-only model made every device
-            # exchangeable across rounds).
-            freq = rng.uniform(1.0, 2.0, N) * self.capability
-            mu = 150.0 / freq
-            alpha = 1.5 * freq ** 2
-            bw = rng.uniform(1.0, 5.0, N) * 1e6  # bit/s
-            nu = self.model_bits / bw
-            p = rng.uniform(0.1, 1.0, N)
-        elif self.profile == "tpu_pod":
-            jitter = rng.lognormal(0.0, 0.25, N)
-            mu = self.base_step_time * jitter / self.capability
-            alpha = 200.0 * mu  # ~200 W replica draw
-            bw = rng.uniform(0.5, 1.0, N) * 100e9  # 100 Gb/s class links
-            nu = self.model_bits / bw
-            p = np.full(N, 300.0)
-        else:
-            raise ValueError(self.profile)
-        ids = (np.arange(self.num_devices) if ids is None
-               else np.asarray(ids, np.int64))
-        if ids.size and (ids.min() < 0 or ids.max() >= N):
-            raise ValueError(f"cohort ids out of range(population={N})")
-        # sigma2/G2 placeholders; overwritten by measured values in training
-        return DeviceReports(sigma2=np.ones(ids.size), G2=np.ones(ids.size),
-                             mu=mu[ids], alpha=alpha[ids], nu=nu[ids],
-                             p=p[ids])
+        with jax.profiler.TraceAnnotation("hcef.reports"):
+            rng = np.random.default_rng(
+                np.random.SeedSequence([self.seed, round_idx]))
+            N = self.population_size
+            if self.profile == "paper_edge":
+                # dynamic U(1, 2) GHz throttle on top of the persistent
+                # capability: a cap-0.5 phone spans [0.5, 1] GHz effective,
+                # a cap-1.0 phone [1, 2] GHz — persistent speed identity
+                # (the paper's U(1, 2)-only model made every device
+                # exchangeable across rounds).
+                freq = rng.uniform(1.0, 2.0, N) * self.capability
+                mu = 150.0 / freq
+                alpha = 1.5 * freq ** 2
+                bw = rng.uniform(1.0, 5.0, N) * 1e6  # bit/s
+                nu = self.model_bits / bw
+                p = rng.uniform(0.1, 1.0, N)
+            elif self.profile == "tpu_pod":
+                jitter = rng.lognormal(0.0, 0.25, N)
+                mu = self.base_step_time * jitter / self.capability
+                alpha = 200.0 * mu  # ~200 W replica draw
+                bw = rng.uniform(0.5, 1.0, N) * 100e9  # 100 Gb/s class links
+                nu = self.model_bits / bw
+                p = np.full(N, 300.0)
+            else:
+                raise ValueError(self.profile)
+            ids = (np.arange(self.num_devices) if ids is None
+                   else np.asarray(ids, np.int64))
+            if ids.size and (ids.min() < 0 or ids.max() >= N):
+                raise ValueError(f"cohort ids out of range(population={N})")
+            # sigma2/G2 placeholders; overwritten by measured values in
+            # training
+            return DeviceReports(sigma2=np.ones(ids.size),
+                                 G2=np.ones(ids.size), mu=mu[ids],
+                                 alpha=alpha[ids], nu=nu[ids], p=p[ids])
 
     # ------------------------------------------------------------------
     def available(self, round_idx: int) -> np.ndarray:

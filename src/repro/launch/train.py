@@ -77,8 +77,17 @@ def compile_step(step, args):
     """AOT-compile a round step for ``args`` with the state (argument 0)
     donated.  Returns (compiled, seconds)."""
     t0 = time.perf_counter()
-    compiled = jax.jit(step, donate_argnums=0).lower(*args).compile()
+    with jax.profiler.TraceAnnotation("hcef.compile"):
+        compiled = jax.jit(step, donate_argnums=0).lower(*args).compile()
     return compiled, time.perf_counter() - t0
+
+
+def _rounds(n: int):
+    """range(n), each round's body inside the profiler step ``hcef.round``
+    (``step_num`` = the round), so a trace's step view shows the rounds."""
+    for rnd in range(n):
+        with jax.profiler.StepTraceAnnotation("hcef.round", step_num=rnd):
+            yield rnd
 
 
 def _gb(n):
@@ -270,7 +279,7 @@ def run(argv=None) -> dict:
               "programs": step_cache}
     ctx = mesh or _null()
     with ctx:
-        for rnd in range(args.rounds):
+        for rnd in _rounds(args.rounds):
             t0 = time.time()
             if pop_store is not None:
                 # rotate this round's cohort into the mesh: scatter the
@@ -322,15 +331,16 @@ def run(argv=None) -> dict:
                 if gossip_round and policy is not None:
                     cluster_levels = cluster_levels_from_theta(
                         theta, hcef.theta_levels, cluster_of)
-            idx = rng.integers(0, n_seq, (R, b_per_dev))
-            if pop_store is not None:
-                batch = {"tokens": jnp.asarray(np.concatenate(
-                    [_shard(int(cohort_ids[d]))[idx[d]]
-                     for d in range(R)]))}
-            else:
-                batch = {"tokens": jnp.asarray(np.concatenate(
-                    [corpus[d, idx[d]] for d in range(R)]))}
-            keys = jax.random.split(jax.random.PRNGKey(1000 + rnd), R)
+            with jax.profiler.TraceAnnotation("hcef.feed"):
+                idx = rng.integers(0, n_seq, (R, b_per_dev))
+                if pop_store is not None:
+                    batch = {"tokens": jnp.asarray(np.concatenate(
+                        [_shard(int(cohort_ids[d]))[idx[d]]
+                         for d in range(R)]))}
+                else:
+                    batch = {"tokens": jnp.asarray(np.concatenate(
+                        [corpus[d, idx[d]] for d in range(R)]))}
+                keys = jax.random.split(jax.random.PRNGKey(1000 + rnd), R)
             # dense_bits=16: het's model_bits above is n_params * 16 (bf16).
             wire_kw = (dict(wire_dtype=hcef.wire_dtype,
                             wire_block=hcef.wire_block, dense_bits=16)
@@ -382,38 +392,39 @@ def run(argv=None) -> dict:
             result["step_s"].append(step_s)
             result["compile_s"].append(csecs)
             result["gossip"].append(gossip_round)
-            if stale_cl:
-                # overlapped accounting: a stale cluster's gossip transfer
-                # hides behind its tau local steps — max, not sum.
-                t, _ = overlap_round_time(
-                    rho, theta, reports.mu, reports.nu, hcef.tau,
-                    cluster_of, gossip=gossip_round,
-                    backhaul=het.backhaul_time(), alive=alive, conn=conn,
-                    stale_clusters=stale_cl, **wire_kw)
-            else:
-                t, _ = round_time(rho, theta, reports.mu, reports.nu,
-                                  hcef.tau, cluster_of,
-                                  gossip=gossip_round,
-                                  backhaul=het.backhaul_time(),
-                                  alive=alive, conn=conn, **wire_kw)
-            e = round_energy(rho, theta, reports.mu, reports.nu,
-                             reports.alpha, reports.p, hcef.tau,
-                             alive=alive, **wire_kw)
-            if pop_store is not None:
-                pop_store.record_round(
-                    cohort_ids, rnd,
-                    energy=per_device_energy(
-                        rho, theta, reports.mu, reports.nu, reports.alpha,
-                        reports.p, hcef.tau, alive=alive, **wire_kw))
-            budget.time_spent_this += t
-            budget.energy_spent_this += e
-            budget.r += 1
-            if gossip_round:
-                budget.time_spent_prev += budget.time_spent_this
-                budget.energy_spent_prev += budget.energy_spent_this
-                budget.time_spent_this = budget.energy_spent_this = 0.0
-                budget.r = 0
-                budget.l += 1
+            with jax.profiler.TraceAnnotation("hcef.budget"):
+                if stale_cl:
+                    # overlapped accounting: a stale cluster's gossip transfer
+                    # hides behind its tau local steps — max, not sum.
+                    t, _ = overlap_round_time(
+                        rho, theta, reports.mu, reports.nu, hcef.tau,
+                        cluster_of, gossip=gossip_round,
+                        backhaul=het.backhaul_time(), alive=alive, conn=conn,
+                        stale_clusters=stale_cl, **wire_kw)
+                else:
+                    t, _ = round_time(rho, theta, reports.mu, reports.nu,
+                                      hcef.tau, cluster_of,
+                                      gossip=gossip_round,
+                                      backhaul=het.backhaul_time(),
+                                      alive=alive, conn=conn, **wire_kw)
+                e = round_energy(rho, theta, reports.mu, reports.nu,
+                                 reports.alpha, reports.p, hcef.tau,
+                                 alive=alive, **wire_kw)
+                if pop_store is not None:
+                    pop_store.record_round(
+                        cohort_ids, rnd,
+                        energy=per_device_energy(
+                            rho, theta, reports.mu, reports.nu, reports.alpha,
+                            reports.p, hcef.tau, alive=alive, **wire_kw))
+                budget.time_spent_this += t
+                budget.energy_spent_this += e
+                budget.r += 1
+                if gossip_round:
+                    budget.time_spent_prev += budget.time_spent_this
+                    budget.energy_spent_prev += budget.energy_spent_this
+                    budget.time_spent_this = budget.energy_spent_this = 0.0
+                    budget.r = 0
+                    budget.l += 1
             chaos_str = ""
             if pop_store is not None and args.population > R:
                 chaos_str += (f" cohort[{int(cohort_ids.min())}.."

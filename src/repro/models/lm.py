@@ -294,17 +294,20 @@ def _moe_ffn(cfg, x, w, pol):
 def _block(cfg, pol, carry, w, *, causal=True, mem_kv=None):
     x, positions = carry
     h = rms_norm(x, w["ln1"], cfg.norm_eps)
-    attn_out, _ = _attention(cfg, h, w, pol, positions, causal=causal,
-                             window=cfg.window)
+    with jax.named_scope("lm.attn"):
+        attn_out, _ = _attention(cfg, h, w, pol, positions, causal=causal,
+                                 window=cfg.window)
     x = x + attn_out
     if mem_kv is not None and "wxq" in w:
         h = rms_norm(x, w["lnx"], cfg.norm_eps)
         x = x + _cross_attention(cfg, h, w, pol, mem_kv)
     h = rms_norm(x, w["ln2"], cfg.norm_eps)
-    if cfg.num_experts:
-        x = x + _moe_ffn(cfg, h, w, pol)
-    else:
-        x = x + _dense_ffn(cfg, h, w, pol)
+    with jax.named_scope("lm.mlp"):
+        if cfg.num_experts:
+            ffn = _moe_ffn(cfg, h, w, pol)
+        else:
+            ffn = _dense_ffn(cfg, h, w, pol)
+    x = x + ffn
     return (constrain(pol, x, "residual"), positions), None
 
 
@@ -313,13 +316,14 @@ def _block(cfg, pol, carry, w, *, causal=True, mem_kv=None):
 # ---------------------------------------------------------------------------
 
 def _embed(cfg, params, batch, pol):
-    tokens = batch["tokens"]
-    x = params["emb"][tokens].astype(dtype_of(cfg.compute_dtype))
-    if cfg.frontend == "vit_stub":
-        P = cfg.frontend_tokens
-        pe = batch["patch_embeds"].astype(x.dtype)
-        x = jnp.concatenate([pe, x[:, P:]], axis=1)
-    return constrain(pol, x, "residual")
+    with jax.named_scope("lm.embed"):
+        tokens = batch["tokens"]
+        x = params["emb"][tokens].astype(dtype_of(cfg.compute_dtype))
+        if cfg.frontend == "vit_stub":
+            P = cfg.frontend_tokens
+            pe = batch["patch_embeds"].astype(x.dtype)
+            x = jnp.concatenate([pe, x[:, P:]], axis=1)
+        return constrain(pol, x, "residual")
 
 
 def _encode(cfg, params, frames, pol):
@@ -333,14 +337,17 @@ def _encode(cfg, params, frames, pol):
 
 
 def _logits(cfg, params, x, pol):
-    x = rms_norm(x, params["final_norm"], cfg.norm_eps)
-    head = (params["emb"].T if cfg.tie_embeddings else params["out_head"])
-    logits = x @ head.astype(x.dtype)
-    logits = softcap(logits, cfg.logits_softcap)
-    if cfg.vocab_padded != cfg.vocab_size:
-        pad_mask = jnp.arange(cfg.vocab_padded) < cfg.vocab_size
-        logits = jnp.where(pad_mask, logits, jnp.asarray(-1e30, logits.dtype))
-    return constrain(pol, logits, "logits")
+    with jax.named_scope("lm.head"):
+        x = rms_norm(x, params["final_norm"], cfg.norm_eps)
+        head = (params["emb"].T if cfg.tie_embeddings
+                else params["out_head"])
+        logits = x @ head.astype(x.dtype)
+        logits = softcap(logits, cfg.logits_softcap)
+        if cfg.vocab_padded != cfg.vocab_size:
+            pad_mask = jnp.arange(cfg.vocab_padded) < cfg.vocab_size
+            logits = jnp.where(pad_mask, logits,
+                               jnp.asarray(-1e30, logits.dtype))
+        return constrain(pol, logits, "logits")
 
 
 def forward(cfg: ModelConfig, params, batch, policy=None):
@@ -379,7 +386,8 @@ def loss_fn(cfg: ModelConfig, params, batch, policy=None):
     if cfg.frontend == "vit_stub":
         pos = jnp.arange(labels.shape[1])
         mask = mask * (pos[None, :] >= cfg.frontend_tokens)
-    return cross_entropy(lg, labels, mask)
+    with jax.named_scope("lm.head"):
+        return cross_entropy(lg, labels, mask)
 
 
 # ---------------------------------------------------------------------------
