@@ -8,6 +8,13 @@ Grid (B, KH, nQ, nKV) with the KV dimension innermost; running (m, l, acc)
 accumulators live in VMEM scratch and the output tile is written on the last
 KV step.  Causal/window blocks that are fully masked are skipped with
 ``pl.when`` (no MXU work issued).
+
+The backward is Pallas too (``flash_attention_pallas_bwd``, FlashAttention-2):
+its residuals are q, k, v, the output and the per-row logsumexp that the
+forward kernel writes when asked (``return_lse``).  A dK/dV kernel (q blocks
+innermost) and a dQ kernel (KV blocks innermost) recompute each live tile's
+probabilities from the logsumexp; all three kernels share the tile mask
+(``_tile``) and the score computation (``_scores``).
 """
 from __future__ import annotations
 
@@ -160,17 +167,12 @@ def paged_decode_attention_pallas(q, k_pages, v_pages, page_table, kv_len, *,
             m.reshape(B, 1, KH, G), l.reshape(B, 1, KH, G))
 
 
-def _kernel(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
-            causal, window, q_offset, scale, bq, bkv, nkv, sq, skv):
-    iq = pl.program_id(2)
-    ikv = pl.program_id(3)
+def _tile(iq, ikv, *, causal, window, q_offset, bq, bkv, skv):
+    """The score tile of q block ``iq`` and KV block ``ikv``.
 
-    @pl.when(ikv == 0)
-    def _init():
-        m_scr[...] = jnp.full_like(m_scr, NEG_INF)
-        l_scr[...] = jnp.zeros_like(l_scr)
-        acc_scr[...] = jnp.zeros_like(acc_scr)
-
+    Returns ``(live, mask)``: ``live`` is false when causal/window mask
+    every pair of the tile, which then issues no MXU work; ``mask()``
+    builds the tile's (bq, bkv) mask, zero-padded tail keys included."""
     q_start = q_offset + iq * bq  # global position of first q row
     k_start = ikv * bkv
 
@@ -180,27 +182,82 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
     if window:
         live &= k_start + bkv - 1 > q_start - window
 
-    @pl.when(live)
-    def _compute():
-        q = q_ref[0].astype(jnp.float32) * scale      # (G, bq, Dh)
-        k = k_ref[0, 0].astype(jnp.float32)           # (bkv, Dh)
-        v = v_ref[0, 0].astype(jnp.float32)           # (bkv, Dh)
-        s = jax.lax.dot_general(q, k, (((2,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32)
-        # s: (G, bq, bkv)
+    def mask():
         qpos = q_start + jax.lax.broadcasted_iota(jnp.int32, (bq, bkv), 0)
         kpos = k_start + jax.lax.broadcasted_iota(jnp.int32, (bq, bkv), 1)
-        mask = jnp.ones((bq, bkv), jnp.bool_)
+        m = jnp.ones((bq, bkv), jnp.bool_)
         if skv % bkv:  # zero-padded tail keys
-            mask &= kpos < skv
+            m &= kpos < skv
         if causal:
-            mask &= kpos <= qpos
+            m &= kpos <= qpos
         if window:
-            mask &= kpos > qpos - window
-        s = jnp.where(mask[None], s, NEG_INF)
+            m &= kpos > qpos - window
+        return m
+
+    return live, mask
+
+
+def _fetch(i, other, n, *, sweep_q, causal, window, q_offset, bq, bkv,
+           skv):
+    """Block index a backward grid step fetches along the axis it sweeps
+    (q blocks when ``sweep_q``, else KV blocks; ``other`` is the other
+    axis' block): ``i`` where the tile is live, else the nearest live
+    block of the sweep, so a skipped step starts no new DMA."""
+    iq, ikv = (i, other) if sweep_q else (other, i)
+    live, _ = _tile(iq, ikv, causal=causal, window=window,
+                    q_offset=q_offset, bq=bq, bkv=bkv, skv=skv)
+    lo, hi = 0, n - 1
+    if sweep_q:
+        k_start = ikv * bkv
+        if causal:
+            lo = jnp.maximum(lo, -((q_offset + bq - 1 - k_start) // bq))
+        if window:
+            hi = jnp.minimum(
+                hi, -((q_offset - k_start - bkv + 1 - window) // bq) - 1)
+    else:
+        q_start = q_offset + iq * bq
+        if causal:
+            hi = jnp.minimum(hi, (q_start + bq - 1) // bkv)
+        if window:
+            lo = jnp.maximum(lo, (q_start - window - bkv + 1) // bkv + 1)
+    near = jnp.maximum(jnp.minimum(jnp.maximum(i, lo), hi), 0)
+    return jnp.where(live, i, near)
+
+
+def _scores(q_ref, k_ref, scale):
+    """The forward's scores of a tile, computed the same way in all three
+    kernels so that the backward's exp(s - lse) is the forward's p."""
+    q = q_ref[0].astype(jnp.float32) * scale      # (G, bq, Dh)
+    k = k_ref[0, 0].astype(jnp.float32)           # (bkv, Dh)
+    s = jax.lax.dot_general(q, k, (((2,), (1,)), ((), ())),
+                            preferred_element_type=jnp.float32)
+    return q, k, s                                # s: (G, bq, bkv)
+
+
+def _kernel(q_ref, k_ref, v_ref, o_ref, *rest, causal, window, q_offset,
+            scale, bq, bkv, nkv, skv):
+    *lse_ref, m_scr, l_scr, acc_scr = rest  # lse_ref only with return_lse
+    iq = pl.program_id(2)
+    ikv = pl.program_id(3)
+
+    @pl.when(ikv == 0)
+    def _init():
+        m_scr[...] = jnp.full_like(m_scr, NEG_INF)
+        l_scr[...] = jnp.zeros_like(l_scr)
+        acc_scr[...] = jnp.zeros_like(acc_scr)
+
+    live, mask = _tile(iq, ikv, causal=causal, window=window,
+                       q_offset=q_offset, bq=bq, bkv=bkv, skv=skv)
+
+    @pl.when(live)
+    def _compute():
+        _, _, s = _scores(q_ref, k_ref, scale)
+        v = v_ref[0, 0].astype(jnp.float32)           # (bkv, Dh)
+        m = mask()[None]
+        s = jnp.where(m, s, NEG_INF)
         m_prev = m_scr[...]
         m_new = jnp.maximum(m_prev, s.max(axis=-1))
-        p = jnp.where(mask[None], jnp.exp(s - m_new[..., None]), 0.0)
+        p = jnp.where(m, jnp.exp(s - m_new[..., None]), 0.0)
         corr = jnp.exp(m_prev - m_new)
         l_scr[...] = l_scr[...] * corr + p.sum(axis=-1)
         pv = jax.lax.dot_general(p, v, (((2,), (0,)), ((), ())),
@@ -210,40 +267,58 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
 
     @pl.when(ikv == nkv - 1)
     def _finalize():
-        out = acc_scr[...] / jnp.maximum(l_scr[...], 1e-20)[..., None]
-        o_ref[0] = out.astype(o_ref.dtype)
+        l = jnp.maximum(l_scr[...], 1e-20)
+        o_ref[0] = (acc_scr[...] / l[..., None]).astype(o_ref.dtype)
+        if lse_ref:
+            lse_ref[0][0, 0] = m_scr[...] + jnp.log(l)
+
+
+def _blocks(Sq, Skv, block_q, block_kv):
+    """Block sizes and padded lengths: a length past one block that is not
+    a multiple of it is zero-padded to one."""
+    bq, bkv = min(block_q, Sq), min(block_kv, Skv)
+    return bq, bkv, Sq + (-Sq) % bq, Skv + (-Skv) % bkv
+
+
+def _heads_major(x, s_pad):
+    """(B, S, N, Dh) -> (B, N, s_pad, Dh), zero-padded along S."""
+    if s_pad > x.shape[1]:
+        x = jnp.pad(x, ((0, 0), (0, s_pad - x.shape[1]), (0, 0), (0, 0)))
+    return x.transpose(0, 2, 1, 3)
 
 
 def flash_attention_pallas(q, k, v, *, causal=True, window=0, q_offset=0,
                            softmax_scale=None, block_q=128, block_kv=128,
-                           interpret=False):
+                           return_lse=False, interpret=False):
     """q: (B, Sq, H, Dh); k, v: (B, Skv, KH, Dh) — same API as ref.
 
     Lengths past one block that are not a multiple of it are zero-padded
-    to one (padded keys are masked, padded query rows dropped)."""
+    to one (padded keys are masked, padded query rows dropped).  With
+    ``return_lse`` the kernel also writes each row's f32 logsumexp and
+    returns ``(out, lse)``, lse (B, H, Sq_padded): the residual of
+    ``flash_attention_pallas_bwd``."""
     B, Sq, H, Dh = q.shape
     _, Skv, KH, _ = k.shape
     G = H // KH
     scale = softmax_scale if softmax_scale is not None else Dh ** -0.5
-    bq = min(block_q, Sq)
-    bkv = min(block_kv, Skv)
-    pad_q, pad_kv = (-Sq) % bq, (-Skv) % bkv
-    if pad_q:
-        q = jnp.pad(q, ((0, 0), (0, pad_q), (0, 0), (0, 0)))
-    if pad_kv:
-        k = jnp.pad(k, ((0, 0), (0, pad_kv), (0, 0), (0, 0)))
-        v = jnp.pad(v, ((0, 0), (0, pad_kv), (0, 0), (0, 0)))
-    nq, nkv = (Sq + pad_q) // bq, (Skv + pad_kv) // bkv
-
-    qt = q.transpose(0, 2, 1, 3)  # (B, H, Sq, Dh)
-    kt = k.transpose(0, 2, 1, 3)  # (B, KH, Skv, Dh)
-    vt = v.transpose(0, 2, 1, 3)
+    bq, bkv, sq_p, skv_p = _blocks(Sq, Skv, block_q, block_kv)
+    nq, nkv = sq_p // bq, skv_p // bkv
 
     kern = functools.partial(
         _kernel, causal=causal, window=window, q_offset=q_offset, scale=scale,
-        bq=bq, bkv=bkv, nkv=nkv, sq=Sq, skv=Skv)
+        bq=bq, bkv=bkv, nkv=nkv, skv=Skv)
+    o_spec = pl.BlockSpec((1, G, bq, Dh), lambda b, h, i, j: (b, h, i, 0))
+    o_shape = jax.ShapeDtypeStruct((B, H, sq_p, Dh), q.dtype)
+    if return_lse:
+        lse_spec = pl.BlockSpec((1, 1, G, bq),
+                                lambda b, h, i, j: (b, h, 0, i))
+        out_specs = [o_spec, lse_spec]
+        out_shape = [o_shape,
+                     jax.ShapeDtypeStruct((B, KH, G, sq_p), jnp.float32)]
+    else:
+        out_specs, out_shape = o_spec, o_shape
 
-    out = pl.pallas_call(
+    res = pl.pallas_call(
         kern,
         grid=(B, KH, nq, nkv),
         in_specs=[
@@ -251,13 +326,166 @@ def flash_attention_pallas(q, k, v, *, causal=True, window=0, q_offset=0,
             pl.BlockSpec((1, 1, bkv, Dh), lambda b, h, i, j: (b, h, j, 0)),
             pl.BlockSpec((1, 1, bkv, Dh), lambda b, h, i, j: (b, h, j, 0)),
         ],
-        out_specs=pl.BlockSpec((1, G, bq, Dh), lambda b, h, i, j: (b, h, i, 0)),
-        out_shape=jax.ShapeDtypeStruct((B, H, Sq + pad_q, Dh), q.dtype),
+        out_specs=out_specs,
+        out_shape=out_shape,
         scratch_shapes=[
             pltpu.VMEM((G, bq), jnp.float32),
             pltpu.VMEM((G, bq), jnp.float32),
             pltpu.VMEM((G, bq, Dh), jnp.float32),
         ],
         interpret=interpret,
-    )(qt, kt, vt)
-    return out.transpose(0, 2, 1, 3)[:, :Sq]
+    )(_heads_major(q, sq_p), _heads_major(k, skv_p), _heads_major(v, skv_p))
+    if not return_lse:
+        return res.transpose(0, 2, 1, 3)[:, :Sq]
+    out, lse = res
+    return out.transpose(0, 2, 1, 3)[:, :Sq], lse.reshape(B, H, sq_p)
+
+
+# ---------------------------------------------------------------------------
+# Backward (FlashAttention-2): dK/dV and dQ kernels
+# ---------------------------------------------------------------------------
+
+def _grad_tile(q_ref, k_ref, v_ref, do_ref, lse_ref, di_ref, mask, scale):
+    """(scaled q, k, dO, p, ds) of a live tile, all f32: the forward's p
+    recomputed as exp(s - lse), and ds = p (dO v^T - D)."""
+    q, k, s = _scores(q_ref, k_ref, scale)
+    m = mask()[None]
+    p = jnp.where(m, jnp.exp(s - lse_ref[0, 0][..., None]), 0.0)
+    do = do_ref[0].astype(jnp.float32)            # (G, bq, Dh)
+    v = v_ref[0, 0].astype(jnp.float32)           # (bkv, Dh)
+    dp = jax.lax.dot_general(do, v, (((2,), (1,)), ((), ())),
+                             preferred_element_type=jnp.float32)
+    ds = p * (dp - di_ref[0, 0][..., None])
+    return q, k, do, p, ds
+
+
+def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, di_ref, dk_ref, dv_ref,
+                dk_scr, dv_scr, *, causal, window, q_offset, scale, bq, bkv,
+                nq, skv):
+    ikv = pl.program_id(2)
+    iq = pl.program_id(3)
+
+    @pl.when(iq == 0)
+    def _init():
+        dk_scr[...] = jnp.zeros_like(dk_scr)
+        dv_scr[...] = jnp.zeros_like(dv_scr)
+
+    live, mask = _tile(iq, ikv, causal=causal, window=window,
+                       q_offset=q_offset, bq=bq, bkv=bkv, skv=skv)
+
+    @pl.when(live)
+    def _compute():
+        q, _, do, p, ds = _grad_tile(q_ref, k_ref, v_ref, do_ref, lse_ref,
+                                     di_ref, mask, scale)
+        # (G, bq, .) -> (G*bq, .): one matmul sums the G query heads
+        rows = p.shape[0] * bq
+        tn = (((0,), (0,)), ((), ()))
+        dv_scr[...] += jax.lax.dot_general(
+            p.reshape(rows, bkv), do.reshape(rows, -1), tn,
+            preferred_element_type=jnp.float32)
+        dk_scr[...] += jax.lax.dot_general(
+            ds.reshape(rows, bkv), q.reshape(rows, -1), tn,
+            preferred_element_type=jnp.float32)
+
+    @pl.when(iq == nq - 1)
+    def _finalize():
+        dk_ref[0, 0] = dk_scr[...].astype(dk_ref.dtype)
+        dv_ref[0, 0] = dv_scr[...].astype(dv_ref.dtype)
+
+
+def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, di_ref, dq_ref, dq_scr,
+               *, causal, window, q_offset, scale, bq, bkv, nkv, skv):
+    iq = pl.program_id(2)
+    ikv = pl.program_id(3)
+
+    @pl.when(ikv == 0)
+    def _init():
+        dq_scr[...] = jnp.zeros_like(dq_scr)
+
+    live, mask = _tile(iq, ikv, causal=causal, window=window,
+                       q_offset=q_offset, bq=bq, bkv=bkv, skv=skv)
+
+    @pl.when(live)
+    def _compute():
+        _, k, _, _, ds = _grad_tile(q_ref, k_ref, v_ref, do_ref, lse_ref,
+                                    di_ref, mask, scale)
+        dq_scr[...] += jax.lax.dot_general(
+            ds, k, (((2,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
+
+    @pl.when(ikv == nkv - 1)
+    def _finalize():
+        dq_ref[0, 0] = (dq_scr[...] * scale).astype(dq_ref.dtype)
+
+
+def flash_attention_pallas_bwd(q, k, v, o, lse, do, *, causal=True,
+                               window=0, q_offset=0, softmax_scale=None,
+                               block_q=128, block_kv=128, interpret=False):
+    """(dq, dk, dv) of ``flash_attention_pallas`` from its residuals: the
+    inputs q, k, v, the output o and ``return_lse``'s lse, given the
+    output's cotangent ``do`` (o's layout and dtype).
+
+    D = rowsum(dO o) is computed here; two kernels then recompute each
+    live tile's p = exp(s - lse).  The dK/dV kernel (grid (B, KH, nKV, nQ),
+    q blocks innermost) sums p^T dO and ds^T q over the G query heads of
+    a KV head; the dQ kernel (grid (B, KH, nQ, nKV)) sums ds k.  Both skip
+    the tiles the forward skips, and keep its f32 operands and
+    accumulators.  dQ is written as (B, KH, G, Sq, Dh), the grouping it
+    is computed in."""
+    B, Sq, H, Dh = q.shape
+    _, Skv, KH, _ = k.shape
+    G = H // KH
+    scale = softmax_scale if softmax_scale is not None else Dh ** -0.5
+    bq, bkv, sq_p, skv_p = _blocks(Sq, Skv, block_q, block_kv)
+    nq, nkv = sq_p // bq, skv_p // bkv
+
+    di = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32), axis=-1)
+    di = jnp.pad(di, ((0, 0), (0, sq_p - Sq), (0, 0)))
+    di = di.transpose(0, 2, 1).reshape(B, KH, G, sq_p)
+    args = (_heads_major(q, sq_p), _heads_major(k, skv_p),
+            _heads_major(v, skv_p), _heads_major(do, sq_p),
+            lse.reshape(B, KH, G, sq_p), di)
+    mask_args = dict(causal=causal, window=window, q_offset=q_offset, bq=bq,
+                     bkv=bkv, skv=Skv)
+
+    def specs(q_block, kv_block):
+        """in_specs of both kernels, given each grid step's blocks."""
+        qi = lambda b, h, x, y: (b, h, q_block(x, y), 0)
+        kvi = lambda b, h, x, y: (b, h, kv_block(x, y), 0)
+        rowi = lambda b, h, x, y: (b, h, 0, q_block(x, y))
+        return [pl.BlockSpec((1, G, bq, Dh), qi),
+                pl.BlockSpec((1, 1, bkv, Dh), kvi),
+                pl.BlockSpec((1, 1, bkv, Dh), kvi),
+                pl.BlockSpec((1, G, bq, Dh), qi),
+                pl.BlockSpec((1, 1, G, bq), rowi),
+                pl.BlockSpec((1, 1, G, bq), rowi)]
+
+    kv_out = pl.BlockSpec((1, 1, bkv, Dh), lambda b, h, j, i: (b, h, j, 0))
+    dk, dv = pl.pallas_call(
+        functools.partial(_dkv_kernel, scale=scale, nq=nq, **mask_args),
+        grid=(B, KH, nkv, nq),
+        in_specs=specs(lambda j, i: _fetch(i, j, nq, sweep_q=True,
+                                            **mask_args),
+                       lambda j, i: j),
+        out_specs=[kv_out, kv_out],
+        out_shape=[jax.ShapeDtypeStruct((B, KH, skv_p, Dh), k.dtype),
+                   jax.ShapeDtypeStruct((B, KH, skv_p, Dh), v.dtype)],
+        scratch_shapes=[pltpu.VMEM((bkv, Dh), jnp.float32),
+                        pltpu.VMEM((bkv, Dh), jnp.float32)],
+        interpret=interpret,
+    )(*args)
+    dq = pl.pallas_call(
+        functools.partial(_dq_kernel, scale=scale, nkv=nkv, **mask_args),
+        grid=(B, KH, nq, nkv),
+        in_specs=specs(lambda i, j: i,
+                       lambda i, j: _fetch(j, i, nkv, sweep_q=False,
+                                           **mask_args)),
+        out_specs=pl.BlockSpec((1, 1, G, bq, Dh),
+                               lambda b, h, i, j: (b, h, 0, i, 0)),
+        out_shape=jax.ShapeDtypeStruct((B, KH, G, sq_p, Dh), q.dtype),
+        scratch_shapes=[pltpu.VMEM((G, bq, Dh), jnp.float32)],
+        interpret=interpret,
+    )(*args)
+    dq = dq.reshape(B, H, sq_p, Dh).transpose(0, 2, 1, 3)[:, :Sq]
+    return (dq, dk.transpose(0, 2, 1, 3)[:, :Skv],
+            dv.transpose(0, 2, 1, 3)[:, :Skv])

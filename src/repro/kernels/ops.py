@@ -16,6 +16,7 @@ import jax.numpy as jnp
 from repro.kernels import ref
 from repro.kernels import wire_pack
 from repro.kernels.flash_attention import (flash_attention_pallas,
+                                           flash_attention_pallas_bwd,
                                            gather_kv_pages,
                                            paged_decode_attention_pallas)
 from repro.kernels.ssd_scan import ssd_pallas
@@ -55,8 +56,10 @@ def _interp():
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
 def _flash_pallas(q, k, v, causal, window, q_offset, softmax_scale,
                   interpret):
-    """Pallas flash forward; the backward is XLA: the VJP of
-    ``ref.flash_attention_jnp`` recomputed from the saved q/k/v."""
+    """Pallas flash attention with a Pallas backward: the fwd rule keeps
+    (q, k, v, o, lse), the forward kernel writing each row's logsumexp;
+    the bwd rule runs the dK/dV and dQ kernels of
+    ``flash_attention_pallas_bwd`` on them."""
     return flash_attention_pallas(
         q, k, v, causal=causal, window=window, q_offset=q_offset,
         softmax_scale=softmax_scale, interpret=interpret)
@@ -64,17 +67,17 @@ def _flash_pallas(q, k, v, causal, window, q_offset, softmax_scale,
 
 def _flash_pallas_fwd(q, k, v, causal, window, q_offset, softmax_scale,
                       interpret):
-    out = _flash_pallas(q, k, v, causal, window, q_offset, softmax_scale,
-                        interpret)
-    return out, (q, k, v)
+    out, lse = flash_attention_pallas(
+        q, k, v, causal=causal, window=window, q_offset=q_offset,
+        softmax_scale=softmax_scale, return_lse=True, interpret=interpret)
+    return out, (q, k, v, out, lse)
 
 
 def _flash_pallas_bwd(causal, window, q_offset, softmax_scale, interpret,
                       res, g):
-    _, vjp = jax.vjp(functools.partial(
-        ref.flash_attention_jnp, causal=causal, window=window,
-        q_offset=q_offset, softmax_scale=softmax_scale), *res)
-    return vjp(g)
+    return flash_attention_pallas_bwd(
+        *res, g, causal=causal, window=window, q_offset=q_offset,
+        softmax_scale=softmax_scale, interpret=interpret)
 
 
 _flash_pallas.defvjp(_flash_pallas_fwd, _flash_pallas_bwd)

@@ -170,20 +170,33 @@ def test_topk_kept_fraction(rng):
         assert abs(kept - theta) < 0.02, (theta, kept)
 
 
-@pytest.mark.parametrize("causal,window", [(True, 0), (True, 24)])
-def test_flash_attention_pallas_grad_vs_ref(causal, window, rng):
-    """The Pallas route's custom VJP (Pallas forward in interpret mode, XLA
-    backward recomputed from q/k/v) matches jax.grad of the plain
-    reference: f32, |err| <= 1e-5 + 1e-4 |ref|."""
-    B, S, H, KH, Dh = 2, 64, 4, 2, 16
-    q = jnp.asarray(rng.normal(size=(B, S, H, Dh)), jnp.float32)
-    k = jnp.asarray(rng.normal(size=(B, S, KH, Dh)), jnp.float32)
-    v = jnp.asarray(rng.normal(size=(B, S, KH, Dh)), jnp.float32)
-    w = jnp.asarray(rng.normal(size=(B, S, H, Dh)), jnp.float32)
+@pytest.mark.parametrize("causal,window,Sq,Skv,H,q_offset", [
+    pytest.param(True, 0, 64, 64, 4, 0, id="True-0"),
+    pytest.param(True, 24, 64, 64, 4, 0, id="True-24"),
+    pytest.param(False, 0, 32, 64, 4, 0, id="cross-sq32-skv64"),
+    pytest.param(True, 0, 32, 64, 4, 32, id="q_offset32"),
+    pytest.param(True, 0, 64, 64, 14, 0, id="gqa-g7"),
+    pytest.param(True, 0, 65, 65, 4, 0, id="s65"),
+    pytest.param(True, 0, 257, 257, 4, 0, id="s257-padded"),
+    pytest.param(True, 100, 257, 257, 4, 0, id="s257-window100"),
+    pytest.param(False, 0, 144, 176, 4, 0, id="cross-padded"),
+])
+def test_flash_attention_pallas_grad_vs_ref(causal, window, Sq, Skv, H,
+                                            q_offset, rng):
+    """The Pallas route's custom VJP (forward writing its logsumexp, then
+    the dK/dV and dQ kernels, all in interpret mode) matches jax.grad of
+    the plain reference: f32, |err| <= 1e-5 + 1e-4 |ref|.  Two KV heads,
+    so G = 2 or 7; 257 = 2 blocks of 128 + 1 pads to three."""
+    B, KH, Dh = 2, 2, 16
+    q = jnp.asarray(rng.normal(size=(B, Sq, H, Dh)), jnp.float32)
+    k = jnp.asarray(rng.normal(size=(B, Skv, KH, Dh)), jnp.float32)
+    v = jnp.asarray(rng.normal(size=(B, Skv, KH, Dh)), jnp.float32)
+    w = jnp.asarray(rng.normal(size=(B, Sq, H, Dh)), jnp.float32)
 
     def loss(fn):
         return lambda q, k, v: jnp.sum(w * fn(q, k, v, causal=causal,
-                                               window=window))
+                                               window=window,
+                                               q_offset=q_offset))
 
     g_pl = jax.grad(loss(functools.partial(ops.flash_attention,
                                            impl="pallas")),
@@ -192,3 +205,29 @@ def test_flash_attention_pallas_grad_vs_ref(causal, window, rng):
     for a, b in zip(g_pl, g_ref):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                    atol=1e-5, rtol=1e-4)
+
+
+def _primitives(jaxpr):
+    """Names of every primitive in a jaxpr and the jaxprs nested in its
+    equations (not inside a Pallas kernel's body)."""
+    for eqn in jaxpr.eqns:
+        yield eqn.primitive.name
+        if eqn.primitive.name == "pallas_call":
+            continue
+        for p in eqn.params.values():
+            for sub in (p if isinstance(p, (tuple, list)) else (p,)):
+                sub = getattr(sub, "jaxpr", sub)
+                if isinstance(sub, jax.extend.core.Jaxpr):
+                    yield from _primitives(sub)
+
+
+def test_flash_attention_pallas_grad_is_three_kernels(rng):
+    """jax.grad on the Pallas route runs the forward kernel and the two
+    backward kernels, and no scan (the jnp route's blockwise loop)."""
+    q = jnp.asarray(rng.normal(size=(1, 64, 4, 16)), jnp.float32)
+    k = jnp.asarray(rng.normal(size=(1, 64, 2, 16)), jnp.float32)
+    grad = jax.grad(lambda q, k, v: jnp.sum(ops.flash_attention(
+        q, k, v, causal=True, impl="pallas")), argnums=(0, 1, 2))
+    prims = list(_primitives(jax.make_jaxpr(grad)(q, k, k).jaxpr))
+    assert prims.count("pallas_call") == 3
+    assert "scan" not in prims

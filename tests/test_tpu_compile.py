@@ -84,6 +84,25 @@ def test_flash_attention_custom_vjp_grad(spec, monkeypatch):
                    *_qkv(spec, S=512))
 
 
+def test_flash_attention_grad_fl_cell(spec, monkeypatch):
+    """value_and_grad of the Pallas route at the Qwen2.5-0.5B FL cell's
+    shape: 4 clients vmapped, 2 sequences of 513 tokens, GQA 14/2 of 64;
+    the dK/dV and dQ kernels lower beside the forward."""
+    monkeypatch.setattr(ops, "_interp", lambda: False)
+    C, B, S, Hq, Hkv = 4, 2, 513, 14, 2
+
+    def loss(q, k, v):
+        o = jax.vmap(lambda q, k, v: ops.flash_attention(
+            q, k, v, causal=True, impl="pallas"))(q, k, v)
+        return jnp.sum(o.astype(jnp.float32))
+
+    text = jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2))).lower(
+        spec((C, B, S, Hq, DH), jnp.bfloat16),
+        spec((C, B, S, Hkv, DH), jnp.bfloat16),
+        spec((C, B, S, Hkv, DH), jnp.bfloat16)).compile().as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 3
+
+
 def test_paged_decode_attention(spec):
     B, P, ps = 8, 16, 16
     NP = 1 + B * P
