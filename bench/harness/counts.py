@@ -4,9 +4,14 @@ kernels' code, so a change that replaces a kernel is judged against the
 same yardstick.
 
 ``c`` is a configuration file of ``bench/configs`` (Hugging Face key
-names).  FLOPs count a multiply-add as 2.
+names).  What a layer holds and what a token goes through in it is its
+block's (``bench/blocks/<model_type>.py``); the attention and dense FFN
+counts here are what blocks build theirs from.  FLOPs count a
+multiply-add as 2.
 """
 from __future__ import annotations
+
+from harness import spec
 
 
 def _dims(c: dict):
@@ -33,22 +38,22 @@ def head_params(c: dict) -> int:
 
 
 def active_matmul_params(c: dict) -> int:
-    """Parameters each token multiplies through: every layer's attention
-    and FFN, and the output head (tied or not).  The embedding lookup is a
-    gather and does no matmul work; norms and biases are left out."""
-    L = c["num_hidden_layers"]
-    return L * (attention_params(c) + ffn_params(c)) + head_params(c)
+    """Parameters each token multiplies through: what every layer's block
+    gives it, and the output head (tied or not).  The embedding lookup is
+    a gather and does no matmul work; norms and biases are left out."""
+    block = spec.load_block(c["model_type"])
+    return c["num_hidden_layers"] * block.active_layer_params(c) \
+        + head_params(c)
 
 
 def total_params(c: dict) -> int:
-    """Every stored parameter: layers (with norms and the q/k/v biases of
-    a ``qwen2`` block), embedding, final norm and an untied head if there
-    is one."""
+    """Every stored parameter: the layers (matrices, and the biases and
+    norms of the block), embedding, final norm and an untied head if
+    there is one."""
     D, L, V = c["hidden_size"], c["num_hidden_layers"], c["vocab_size"]
-    _, H, KH, Dh = _dims(c)
-    bias = (H + 2 * KH) * Dh if c["model_type"] == "qwen2" else 0
-    per_layer = attention_params(c) + ffn_params(c) + bias + 2 * D
-    n = L * per_layer + V * D + D
+    block = spec.load_block(c["model_type"])
+    n = L * (block.stored_layer_params(c) + block.extra_params(c)) \
+        + V * D + D
     if not c.get("tie_word_embeddings", True):
         n += V * D
     return n

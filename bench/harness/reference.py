@@ -2,14 +2,15 @@
 matmul precision, written from the published architecture and the HCEF
 algorithm alone.  It imports nothing of the program.
 
-The architecture is the configuration file's ``model_type``: ``qwen2``
-(RMSNorm, rotary embedding with halves rotated, q/k/v projections with
-bias, softmax attention scaled by 1/sqrt(head_dim), SwiGLU FFN, output
-head tied to the embedding).  Parameters come in the layout the
-benchmark's weight maker fills (``emb``, ``final_norm`` and per-layer
-leaves stacked on a leading layer axis: ``ln1 ln2 wq wk wv wo bq bk bv
-w_gate w_up w_down``).  The embedding may hold more rows than the
-vocabulary; only the first ``vocab_size`` are used.
+The architecture is the configuration file's ``model_type``: its block
+module (``bench/blocks/<model_type>.py``, found by ``spec.load_block``)
+writes the forward from the primitives kept here: ``mm`` (a matmul at
+``HIGHEST``, with the fp8 control), ``rms_norm``, ``rope`` and
+``attention`` (causal GQA softmax attention at a given scale).
+Parameters come in the layout the benchmark's weight maker fills
+(``emb``, ``final_norm`` and per-layer leaves stacked on a leading layer
+axis).  The embedding may hold more rows than the vocabulary; only the
+first ``vocab_size`` are used.
 
 ``lowp="fp8"`` switches every matmul to the precision below the
 configuration's bfloat16 (the control): each operand is scaled per tensor
@@ -24,6 +25,8 @@ import math
 import jax
 import jax.numpy as jnp
 import numpy as np
+
+from harness import spec
 
 HIGHEST = jax.lax.Precision.HIGHEST
 
@@ -61,7 +64,7 @@ def _cast(x, lowp):
     return _fp8(x)
 
 
-def _mm(eq, a, b, lowp):
+def mm(eq, a, b, lowp):
     return jnp.einsum(eq, _cast(a, lowp), _cast(b, lowp), precision=HIGHEST)
 
 
@@ -80,55 +83,26 @@ def rope(x, pos, theta):
     return jnp.concatenate([x1 * cos - x2 * sin, x1 * sin + x2 * cos], -1)
 
 
-QKV_BIAS = {"qwen2": True}
-
-
-def _layer(c, w, x, pos, lowp):
-    B, S, D = x.shape
-    H, KH = c["num_attention_heads"], c["num_key_value_heads"]
-    Dh = c.get("head_dim") or D // H
-    eps = c["rms_norm_eps"]
-    h = rms_norm(x, w["ln1"], eps)
-    q = _mm("bsd,de->bse", h, w["wq"], lowp)
-    k = _mm("bsd,de->bse", h, w["wk"], lowp)
-    v = _mm("bsd,de->bse", h, w["wv"], lowp)
-    if QKV_BIAS[c["model_type"]]:
-        q, k, v = q + w["bq"], k + w["bk"], v + w["bv"]
-    q, k = q.reshape(B, S, H, Dh), k.reshape(B, S, KH, Dh)
-    v = v.reshape(B, S, KH, Dh)
-    q, k = rope(q, pos, c["rope_theta"]), rope(k, pos, c["rope_theta"])
+def attention(q, k, v, pos, scale, lowp):
+    """Causal softmax attention of q (B, S, H, Dh) over k and v
+    (B, S, KH, Dh), each KV head shared by H / KH query heads, scores
+    scaled by ``scale``; returns (B, S, H * Dh)."""
+    B, S, H, Dh = q.shape
+    KH = k.shape[2]
     k = jnp.repeat(k, H // KH, axis=2)
     v = jnp.repeat(v, H // KH, axis=2)
-    s = _mm("bqhd,bkhd->bhqk", q, k, lowp) / math.sqrt(Dh)
+    s = mm("bqhd,bkhd->bhqk", q, k, lowp) * scale
     causal = pos[:, None, :, None] >= pos[:, None, None, :]
     s = jnp.where(causal, s, -jnp.inf)
     p = jax.nn.softmax(s, axis=-1)
-    o = _mm("bhqk,bkhd->bqhd", p, v, lowp).reshape(B, S, H * Dh)
-    x = x + _mm("bse,ed->bsd", o, w["wo"], lowp)
-    h = rms_norm(x, w["ln2"], eps)
-    a = jax.nn.silu(_mm("bsd,df->bsf", h, w["w_gate"], lowp)) \
-        * _mm("bsd,df->bsf", h, w["w_up"], lowp)
-    return x + _mm("bsf,fd->bsd", a, w["w_down"], lowp)
+    return mm("bhqk,bkhd->bqhd", p, v, lowp).reshape(B, S, H * Dh)
 
 
 def logits(c, params, tokens, lowp=None, positions=None):
-    """(B, S, vocab) logits of a causal forward over ``tokens``."""
-    V = c["vocab_size"]
-    f32 = lambda t: jax.tree.map(lambda a: a.astype(jnp.float32), t)
-    emb = params["emb"][:V].astype(jnp.float32)
-    x = emb[tokens]
-    B, S = tokens.shape
-    pos = (jnp.broadcast_to(jnp.arange(S), (B, S)) if positions is None
-           else positions)
-    layers = params["layers"]
-
-    def body(x, w):
-        return _layer(c, f32(w), x, pos, lowp), None
-
-    x, _ = jax.lax.scan(body, x, layers)
-    x = rms_norm(x, params["final_norm"].astype(jnp.float32),
-                 c["rms_norm_eps"])
-    return _mm("bsd,vd->bsv", x, emb, lowp)
+    """(B, S, vocab) logits of a causal forward over ``tokens``, by the
+    block of the configuration's ``model_type``."""
+    return spec.load_block(c["model_type"]).logits(c, params, tokens, lowp,
+                                                   positions)
 
 
 def loss(c, params, tokens, lowp=None):
@@ -205,8 +179,8 @@ def _local_steps(c, tau, eta, momentum, lowp, rows):
             tok = tok[:rows]
         pf = store(lambda a: a.astype(jnp.float32), p)
         l, g = jax.value_and_grad(lambda q: loss(c, q, tok, lowp))(pf)
-        m = store(lambda mm, gg: momentum * mm + gg, m, g)
-        p = store(lambda a, mm: (a.astype(jnp.float32) - eta * mm)
+        m = store(lambda mo, gg: momentum * mo + gg, m, g)
+        p = store(lambda a, mo: (a.astype(jnp.float32) - eta * mo)
                   .astype(a.dtype), p, m)
         return (p, m), l
 

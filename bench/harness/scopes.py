@@ -22,6 +22,11 @@ Each op of chip 0 in the window gives its self time
 - ``aggregate``: ``hcef.aggregate`` and ``hcef.gossip``;
 - ``unscoped``: everything else (other programs of the window, ops the
   compiler made with no name, ops of no module whose text is known).
+
+A reader that needs one scope rather than a phase takes ``scope_ms`` (the
+self time of the ops with that scope anywhere in their ``op_name``,
+forward and backward apart) or ``scope_ops`` (those ops themselves).  All
+three read one naming of the window's ops, made once per run.
 """
 from __future__ import annotations
 
@@ -129,21 +134,27 @@ def _best_map(ops: Sequence[tr.Event], maps: Sequence[dict]):
     return best
 
 
+def in_scope(op_name: str, scope: str) -> bool:
+    """Whether ``scope`` is one of the names of ``op_name``'s path (a
+    path component, or the argument of a transform such as
+    ``transpose(jvp(lm.head))``)."""
+    return re.search(r"(?:^|[/(])" + re.escape(scope) + r"(?=$|[/)])",
+                     op_name) is not None
+
+
 @dataclass
-class Phases:
-    """Device self time of a window by phase, in ns."""
-    ns: Dict[str, float]
-    remat_ns: float  # the part of ``local_bwd`` that re-runs the forward
+class NamedOps:
+    """The ops of one chip in a window, each with its self time there."""
+    ops: List[tuple]  # (event, self ns, op_name), op_name "" if unknown
     modules_named: int  # module executions read with a program's text
     modules: int
-    top: Dict[str, Counter] = field(repr=False)  # phase -> op -> ns
 
 
-def phase_ns(events: Iterable[tr.Event], plane: str, t0: float, t1: float,
-             maps: Sequence[dict]) -> Phases:
-    """Each op's self time in [t0, t1] on ``plane`` given to its phase.
-    ``maps``: one ``hlo_op_names`` map per program the window may have
-    run; an op of a module execution no map names is ``unscoped``."""
+def named_ops(events: Iterable[tr.Event], plane: str, t0: float, t1: float,
+              maps: Sequence[dict]) -> NamedOps:
+    """Each op with self time in [t0, t1] on ``plane``, named by the map
+    (one ``hlo_op_names`` map per program the window may have run) that
+    names its module execution; an op no map names has the name ""."""
     events = list(events)
     ops = sorted(tr.select(events, plane=plane, line=tr.OPS_LINE),
                  key=lambda e: e.start_ns)
@@ -164,18 +175,40 @@ def phase_ns(events: Iterable[tr.Event], plane: str, t0: float, t1: float,
             k, _ = event_key(e.name)
             if k in m:
                 name_of[id(e)] = m[k][1]
-    out = Phases(dict.fromkeys(PHASES, 0.0), 0.0, named, len(mods),
-                 {p: Counter() for p in PHASES})
-    for e, own in tr.self_times(ops, t0, t1):
-        if own <= 0:
-            continue
-        on = name_of.get(id(e), "")
+    return NamedOps([(e, own, name_of.get(id(e), ""))
+                     for e, own in tr.self_times(ops, t0, t1) if own > 0],
+                    named, len(mods))
+
+
+@dataclass
+class Phases:
+    """Device self time of a window by phase, in ns."""
+    ns: Dict[str, float]
+    remat_ns: float  # the part of ``local_bwd`` that re-runs the forward
+    modules_named: int  # module executions read with a program's text
+    modules: int
+    top: Dict[str, Counter] = field(repr=False)  # phase -> op -> ns
+
+
+def phases(named: NamedOps) -> Phases:
+    """Each named op's self time given to its phase."""
+    out = Phases(dict.fromkeys(PHASES, 0.0), 0.0, named.modules_named,
+                 named.modules, {p: Counter() for p in PHASES})
+    for e, own, on in named.ops:
         ph = phase_of(on)
         out.ns[ph] += own
         out.top[ph][tr.short_name(e.name)] += own
         if ph == "local_bwd" and REMAT in on:
             out.remat_ns += own
     return out
+
+
+def phase_ns(events: Iterable[tr.Event], plane: str, t0: float, t1: float,
+             maps: Sequence[dict]) -> Phases:
+    """Each op's self time in [t0, t1] on ``plane`` given to its phase.
+    ``maps``: one ``hlo_op_names`` map per program the window may have
+    run; an op of a module execution no map names is ``unscoped``."""
+    return phases(named_ops(events, plane, t0, t1, maps))
 
 
 def fl_round_maps(ctx) -> List[dict]:
@@ -210,6 +243,19 @@ def fl_round_maps(ctx) -> List[dict]:
         for g in (False, True)]
 
 
+def window_ops(ctx) -> Optional[NamedOps]:
+    """The named ops of an fl cell's traced window on chip 0, or None
+    without one.  Made once per run and kept on ``ctx``, so the round
+    programs are compiled for their names once, whatever reads them."""
+    if "_window_ops" not in vars(ctx):
+        ctx._window_ops = None
+        v = ctx.trace
+        if ctx.kind == "fl" and v is not None and v.planes and ctx.rounds:
+            ctx._window_ops = named_ops(v.events, v.planes[0], v.t0, v.t1,
+                                        fl_round_maps(ctx))
+    return ctx._window_ops
+
+
 def phase_ms(ctx) -> Optional[Dict[str, float]]:
     """{phase: device ms per round of the traced window} of an fl cell on
     chip 0, or None where no op of the window carries a program scope (a
@@ -219,12 +265,13 @@ def phase_ms(ctx) -> Optional[Dict[str, float]]:
     if "_phase_ms" in vars(ctx):
         return ctx._phase_ms
     ctx._phase_ms = None
-    v = ctx.trace
-    if ctx.kind != "fl" or v is None or not v.planes or not ctx.rounds:
+    named = window_ops(ctx)
+    if named is None:
         return None
-    ph = phase_ns(v.events, v.planes[0], v.t0, v.t1, fl_round_maps(ctx))
+    ph = phases(named)
     if ph.ns["unscoped"] == sum(ph.ns.values()):
         return None
+    v = ctx.trace
     ops = tr.select(v.events, plane=v.planes[0], line=tr.OPS_LINE)
     busy = tr.busy_ns(ops, v.t0, v.t1)
     per = 1e-6 / ctx.rounds
@@ -240,6 +287,31 @@ def phase_ms(ctx) -> Optional[Dict[str, float]]:
                                   for n, x in c.most_common(4)]
                               for k, c in ph.top.items()})
     return ctx._phase_ms
+
+
+def scope_ops(ctx, scope: str) -> Optional[List[tuple]]:
+    """(event, self ns, op_name) of each op of the traced window on chip 0
+    with ``scope`` in its ``op_name`` (``in_scope``), or None without a
+    traced window."""
+    named = window_ops(ctx)
+    if named is None:
+        return None
+    return [op for op in named.ops if in_scope(op[2], scope)]
+
+
+def scope_ms(ctx, scope: str) -> Optional[Dict[str, float]]:
+    """{"fwd": ms, "bwd": ms}: device self time per round of the traced
+    window on chip 0 of the ops under ``scope``, outside and inside an
+    autodiff ``transpose(...)`` (the backward, with any remat re-run of
+    the forward); None where no op carries the scope."""
+    ops = scope_ops(ctx, scope)
+    if not ops:
+        return None
+    ns = {"fwd": 0.0, "bwd": 0.0}
+    for _, own, on in ops:
+        ns["bwd" if "transpose(" in on else "fwd"] += own
+    per = 1e-6 / ctx.rounds
+    return {k: x * per for k, x in ns.items()}
 
 
 def _fmt(d):

@@ -1,6 +1,8 @@
 """Name resolution: everything a cell needs is found by the names in
 ``BENCHMARK.json``, so a later change adds a configuration, a traffic mix,
-a metric or a cell as new files and entries and edits none."""
+a metric or a cell as new files and entries and edits none.  A
+configuration's architecture is found by its file's ``model_type``
+(``bench/blocks/<model_type>.py``)."""
 from __future__ import annotations
 
 import importlib.util
@@ -11,6 +13,7 @@ from typing import Callable, List, Optional
 
 BENCH_DIR = Path(__file__).resolve().parents[1]
 ROOT = BENCH_DIR.parent
+BLOCKS_DIR = BENCH_DIR / "blocks"
 
 
 class SpecError(Exception):
@@ -66,6 +69,10 @@ def reader_path(metric: str) -> Path:
     return BENCH_DIR / "metrics" / f"{metric}.py"
 
 
+def block_path(model_type: str) -> Path:
+    return BLOCKS_DIR / f"{model_type}.py"
+
+
 def _metric(d: dict) -> Metric:
     return Metric(name=d["name"], unit=d["unit"], better=d["better"],
                   source=d["source"], moves=d.get("moves"),
@@ -107,25 +114,47 @@ def resolve(workload: str, root: Path = ROOT) -> Cell:
     for p in (cfg_path, tr_path, lim_path):
         if not p.is_file():
             raise SpecError(f"workload {workload!r}: missing {p}")
+    config = load_json(cfg_path)
+    if not block_path(config["model_type"]).is_file():
+        raise SpecError(f"workload {workload!r}: no block for model_type "
+                        f"{config['model_type']!r}: "
+                        f"{block_path(config['model_type'])}")
     e2e, per = cell_metrics(bench, workload)
     for m in e2e + per:
         if not reader_path(m.name).is_file():
             raise SpecError(f"metric {m.name!r} has no reader "
                             f"{reader_path(m.name)}")
     return Cell(name=workload, chips=int(w["chips"]),
-                config_name=w["config"], config=load_json(cfg_path),
+                config_name=w["config"], config=config,
                 traffic_name=w["traffic"], traffic=load_json(tr_path),
                 limits=load_json(lim_path), end_to_end=e2e, per_layer=per)
 
 
-def load_reader(metric: str) -> Callable:
-    """The ``read(ctx)`` function of ``bench/metrics/<metric>.py``."""
-    path = reader_path(metric)
-    spec = importlib.util.spec_from_file_location(
-        "bench_metric_" + metric.replace(".", "_").replace("-", "_"), path)
+def _load_module(prefix: str, path: Path):
+    name = prefix + path.stem.replace(".", "_").replace("-", "_")
+    spec = importlib.util.spec_from_file_location(name, path)
     mod = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)
-    return mod.read
+    return mod
+
+
+def load_block(model_type: str):
+    """The architecture module ``bench/blocks/<model_type>.py``:
+    ``program(c, m)`` (the program's ModelConfig of the block at the
+    configuration file's sizes, ``m`` the repo configuration it names),
+    ``logits(c, params, tokens, lowp, positions)`` (the plain reference's
+    forward), and the per-layer parameter counts
+    ``stored_layer_params(c)``, ``active_layer_params(c)`` and
+    ``extra_params(c)``."""
+    path = block_path(model_type)
+    if not path.is_file():
+        raise SpecError(f"no block for model_type {model_type!r}: {path}")
+    return _load_module("bench_block_", path)
+
+
+def load_reader(metric: str) -> Callable:
+    """The ``read(ctx)`` function of ``bench/metrics/<metric>.py``."""
+    return _load_module("bench_metric_", reader_path(metric)).read
 
 
 def load_kind(kind: str):
@@ -134,8 +163,4 @@ def load_kind(kind: str):
     path = BENCH_DIR / "kinds" / f"{kind}.py"
     if not path.is_file():
         raise SpecError(f"no driver for traffic kind {kind!r}: {path}")
-    spec = importlib.util.spec_from_file_location(f"bench_kind_{kind}",
-                                                  path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod
+    return _load_module("bench_kind_", path)

@@ -234,3 +234,80 @@ def test_phase_readers_read_nothing_without_scopes(recorded, monkeypatch):
     no_trace = _ctx(ev, w, {})
     no_trace.trace = None
     assert spec.load_reader("vocab_ms.train")(no_trace) is None
+
+
+@pytest.mark.parametrize("op_name,scope,inside", [
+    (f"{STEP}/jvp()/while/body/closed_call/lm.attn/pallas_call", "lm.attn",
+     True),
+    (f"{STEP}/transpose(jvp(lm.head))/dot_general", "lm.head", True),
+    ("jit(round_step)/vmap(hcef.delta)/convert_element_type", "hcef.delta",
+     True),
+    ("hcef.compress", "hcef.compress", True),
+    (f"{STEP}/hcef.local_step/jvp()/lm.attn/dot_general", "hcef.local_step",
+     True),
+    (f"{STEP}/jvp()/lm.attention/dot_general", "lm.attn", False),
+    (f"{STEP}/jvp()/xlm.attn/dot_general", "lm.attn", False),
+    (f"{STEP}/jvp()/lm.mlp/dot_general", "lm.attn", False),
+    ("", "lm.attn", False),
+])
+def test_an_op_name_is_in_a_scope_by_its_path(op_name, scope, inside):
+    assert scopes.in_scope(op_name, scope) is inside
+
+
+def _whole_window(recorded, monkeypatch):
+    """A ctx over all the recorded slices, and the list of the times the
+    round programs were compiled for their names."""
+    ev, windows, maps = recorded
+    compiled = []
+
+    def round_maps(ctx):
+        compiled.append(ctx)
+        return list(maps.values())
+
+    monkeypatch.setattr(scopes, "fl_round_maps", round_maps)
+    t0 = min(w[0] for w in windows.values())
+    t1 = max(w[1] for w in windows.values())
+    return _ctx(ev, (t0, t1), {}), compiled
+
+
+def test_scope_ms_reads_the_recorded_scopes(recorded, monkeypatch):
+    ctx, compiled = _whole_window(recorded, monkeypatch)
+    ph = scopes.phase_ms(ctx)
+    compress = scopes.scope_ms(ctx, "hcef.compress")
+    assert compress == {"fwd": ph["compress"], "bwd": 0.0}
+    attn = scopes.scope_ms(ctx, "lm.attn")
+    assert attn["fwd"] > 0 and attn["bwd"] > 0
+    assert attn["fwd"] + attn["bwd"] <= ph["local_fwd"] + ph["local_bwd"]
+    head = scopes.scope_ms(ctx, "lm.head")
+    assert 0 < head["fwd"] + head["bwd"] <= ph["vocab"]
+    assert scopes.scope_ms(ctx, "lm.nosuch") is None
+    # the phases still sum to the busy time
+    v = ctx.trace
+    busy = tr.busy_ns(tr.select(v.events, plane=PLANE, line=tr.OPS_LINE),
+                      v.t0, v.t1)
+    assert sum(ph.values()) == pytest.approx(busy * 1e-6, rel=1e-9)
+    # one naming of the window's ops serves every reader
+    assert len(compiled) == 1
+
+
+def test_scope_ops_are_the_window_ops_under_the_scope(recorded, monkeypatch):
+    ctx, compiled = _whole_window(recorded, monkeypatch)
+    ops = scopes.scope_ops(ctx, "lm.attn")
+    assert ops and all(scopes.in_scope(on, "lm.attn") for _, _, on in ops)
+    v = ctx.trace
+    assert all(e.plane == PLANE and own > 0 and e.end_ns > v.t0
+               and e.start_ns < v.t1 for e, own, _ in ops)
+    assert {scopes.phase_of(on) for _, _, on in ops} == {"local_fwd",
+                                                         "local_bwd"}
+    ms = scopes.scope_ms(ctx, "lm.attn")
+    assert sum(own for _, own, _ in ops) * 1e-6 == pytest.approx(
+        ms["fwd"] + ms["bwd"])
+    assert len(compiled) == 1
+
+
+def test_scope_readings_need_a_trace(recorded):
+    ev, windows, _ = recorded
+    ctx = _ctx(ev, next(iter(windows.values())), {})
+    ctx.trace = None
+    assert scopes.scope_ms(ctx, "lm.attn") is None
+    assert scopes.scope_ops(ctx, "lm.attn") is None
