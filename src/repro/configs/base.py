@@ -50,6 +50,15 @@ class ModelConfig:
     norm_eps: float = 1e-6
     rope_theta: float = 10_000.0
     logits_softcap: float = 0.0
+    # --- muP-style multipliers (Granite); the defaults add no operation:
+    # token embeddings times embedding_multiplier, softmax scale
+    # attention_multiplier (0: 1/sqrt(head_dim)), each residual branch
+    # times residual_multiplier, logits divided by logits_scaling.  The
+    # repr shows one only where it is set (``__repr__``).
+    embedding_multiplier: float = field(default=1.0, repr=False)
+    attention_multiplier: float = field(default=0.0, repr=False)
+    residual_multiplier: float = field(default=1.0, repr=False)
+    logits_scaling: float = field(default=1.0, repr=False)
     # --- dtypes ---
     param_dtype: str = "bfloat16"
     compute_dtype: str = "bfloat16"
@@ -70,6 +79,15 @@ class ModelConfig:
     @property
     def ssm_heads(self) -> int:
         return self.d_inner // self.ssm_head_dim
+
+    def __repr__(self) -> str:
+        """The dataclass repr, with each multiplier shown only where it
+        differs from its neutral default: a configuration without them
+        reads as before they existed (recorded readings compare it)."""
+        shown = [f"{f.name}={getattr(self, f.name)!r}"
+                 for f in dataclasses.fields(self)
+                 if f.repr or getattr(self, f.name) != f.default]
+        return f"{type(self).__name__}({', '.join(shown)})"
 
     def replace(self, **kw) -> "ModelConfig":
         return dataclasses.replace(self, **kw)

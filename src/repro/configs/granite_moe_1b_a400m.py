@@ -2,6 +2,10 @@
 [hf:ibm-granite/granite-3.0-1b-a400m-base; hf]
 
 24L d_model=1024 16H (GQA kv=8) d_ff=512 (expert width) vocab=49155.
+Dropless routing (softmax over the 8 chosen router logits), no bias, tied
+head; muP multipliers: embeddings x12, softmax scale 1/64, residual
+branches x0.22, logits / 6.  attention_dropout 0.1 is a training-time
+dropout, left out.
 """
 from repro.configs.base import (ArchBundle, FLTopology, FULL_ATTN_LONG_SKIP,
                                 ModelConfig)
@@ -19,6 +23,10 @@ MODEL = ModelConfig(
     num_experts=32,
     experts_per_token=8,
     tie_embeddings=True,
+    embedding_multiplier=12.0,
+    attention_multiplier=1 / 64,
+    residual_multiplier=0.22,
+    logits_scaling=6.0,
 )
 
 CONFIG = ArchBundle(

@@ -225,19 +225,20 @@ def make_round_step(cfg: ModelConfig, hcef: HCEFConfig, topo: FLTopology,
         def step(carry, inp):
             p, m = carry
             batch_s, bit = inp
-            loss, g = jax.value_and_grad(
-                lambda pp: model.loss_fn(cfg, pp, batch_s, policy))(p)
+            (loss, load), g = jax.value_and_grad(
+                lambda pp: model.loss_and_load(cfg, pp, batch_s, policy),
+                has_aux=True)(p)
             with jax.named_scope("hcef.grad_stats"):
                 gn2 = _global_norm2(g)
                 g = jax.tree.map(lambda a: a * bit.astype(a.dtype), g)
             with jax.named_scope("hcef.sgd"):
                 p, m = sgd_update(p, g, m, lr=hcef.eta, momentum=hcef.momentum)
-            return (p, m), (loss, gn2, bit)
+            return (p, m), (loss, gn2, bit, load)
 
         # the scope covers the loop as well as its body: the loop's carry,
         # the zeros autodiff makes for cotangents, the stacked outputs
         with jax.named_scope("hcef.local_step"):
-            (params, mom), (losses, gn2s, bits_out) = jax.lax.scan(
+            (params, mom), (losses, gn2s, bits_out, loads) = jax.lax.scan(
                 step, (params, mom), (batch_tau, bits))
         with jax.named_scope("hcef.delta"):
             delta = jax.tree.map(
@@ -250,6 +251,9 @@ def make_round_step(cfg: ModelConfig, hcef: HCEFConfig, topo: FLTopology,
             sigma2_est = jnp.maximum(jnp.mean(gn2s) - g2_est, 0.0)
             metrics = {"loss": jnp.mean(losses), "g2": g2_est,
                        "sigma2": sigma2_est, "steps": jnp.sum(bits_out)}
+        if loads is not None:
+            # assignments per expert of the last local step, all layers
+            metrics["moe_load"] = loads[-1]
         return delta, mom, metrics
 
     spmd = tuple(policy.replica_axes) if (

@@ -52,10 +52,6 @@ class Policy:
     def axis_size(self, axes) -> int:
         return int(np.prod([self.mesh.shape[a] for a in axes], initial=1))
 
-    def seq_blocks(self) -> int:
-        """Number of sequence shards (MoE routing block count)."""
-        return max(1, self.axis_size(self.seq_axes))
-
     # -- shardings ----------------------------------------------------------
 
     def replicated(self) -> NamedSharding:
@@ -141,21 +137,9 @@ class Policy:
         elif kind == "cache" and x.ndim >= 2:
             if self._dim_ok(shape, 1, self.seq_axes):
                 spec[1] = s  # flash-decode: KV sequence over seq shards
-        elif kind == "moe_tokens" and x.ndim == 4:
-            if self._dim_ok(shape, 1, self.tensor_axes):
-                spec[1] = t  # routing blocks stay seq-shard-aligned
-        elif kind == "moe_dispatch" and x.ndim == 5:
-            if self._dim_ok(shape, 2, self.tensor_axes):
-                spec[2] = t  # block -> expert reshard (all-to-all)
-        elif kind == "moe_return" and x.ndim == 5:
-            if self._dim_ok(shape, 1, self.tensor_axes):
-                spec[1] = t  # expert -> block reshard back
-        elif kind == "moe_w_in" and x.ndim == 3:
+        elif kind in ("moe_w_in", "moe_w_out") and x.ndim == 3:
             if self._dim_ok(shape, 0, self.tensor_axes):
-                spec[0] = t
-        elif kind == "moe_w_out" and x.ndim == 3:
-            if self._dim_ok(shape, 0, self.tensor_axes):
-                spec[0] = t
+                spec[0] = t  # experts over model
 
         return jax.lax.with_sharding_constraint(
             x, NamedSharding(self.mesh, P(*spec)))

@@ -19,6 +19,7 @@ from repro.kernels.flash_attention import (flash_attention_pallas,
                                            flash_attention_pallas_bwd,
                                            gather_kv_pages,
                                            paged_decode_attention_pallas)
+from repro.kernels.grouped_matmul import grouped_matmul_pallas
 from repro.kernels.ssd_scan import ssd_pallas
 from repro.kernels.topk_compress import topk_compress_pallas
 
@@ -159,6 +160,23 @@ def paged_decode_attention(q, k_pages, v_pages, page_table, kv_len, *,
     return ref.decode_attention_jnp(q, k, v, kv_len=kv_len,
                                     softmax_scale=softmax_scale,
                                     return_stats=True)
+
+
+def grouped_matmul(x, w, group_sizes, *, preferred_element_type=None,
+                   impl=None):
+    """Rows of x (m, k) in consecutive groups, group g times w[g]
+    (G, k, n); group_sizes (G,) int32 sum to m.  Returns (m, n) in
+    ``preferred_element_type`` (default x's dtype), accumulated in f32.
+
+    Pallas route: the megablox kernels, one launch per call, also under
+    vmap (``kernels/grouped_matmul.py``); jnp route: ``lax.ragged_dot``."""
+    out = jnp.dtype(preferred_element_type or x.dtype)
+    r = _route(impl)
+    if r == "pallas":
+        return grouped_matmul_pallas(x, w, group_sizes, out, _interp())
+    if r == "ref":
+        return ref.grouped_matmul_ref(x, w, group_sizes).astype(out)
+    return jax.lax.ragged_dot(x, w, group_sizes, preferred_element_type=out)
 
 
 def ssd(x, dt, A, B, C, *, chunk=64, impl=None):

@@ -19,6 +19,21 @@ NEG_INF = -1e30
 
 
 # ---------------------------------------------------------------------------
+# Grouped matmul
+# ---------------------------------------------------------------------------
+
+def grouped_matmul_ref(x, w, group_sizes):
+    """Each row of x (m, k) times the weight of its group, w (G, k, n);
+    groups are consecutive runs of rows, group_sizes (G,) summing to m.
+    Oracle only (gathers a (k, n) matrix per row), f32 at HIGHEST."""
+    ends = jnp.cumsum(group_sizes)
+    gid = jnp.searchsorted(ends, jnp.arange(x.shape[0]), side="right")
+    return jnp.einsum("mk,mkn->mn", x.astype(jnp.float32),
+                      w.astype(jnp.float32)[gid],
+                      precision=jax.lax.Precision.HIGHEST)
+
+
+# ---------------------------------------------------------------------------
 # Attention
 # ---------------------------------------------------------------------------
 

@@ -389,6 +389,13 @@ def run(argv=None) -> dict:
             step_s = time.perf_counter() - t_step
             loss = float(m["loss"].mean())
             result["losses"].append(loss)
+            load_str = ""
+            if "moe_load" in m:
+                # uneven routing on record: the busiest expert over the mean
+                load = np.asarray(m["moe_load"]).sum(axis=0)
+                skew = float(load.max() / max(load.mean(), 1e-9))
+                result.setdefault("moe_load_max_over_mean", []).append(skew)
+                load_str = f" moe_load_max_over_mean={skew:.3f}"
             result["step_s"].append(step_s)
             result["compile_s"].append(csecs)
             result["gossip"].append(gossip_round)
@@ -441,7 +448,7 @@ def run(argv=None) -> dict:
                   f"rho={np.mean(rho):.2f} theta={np.mean(theta):.2f} "
                   f"sim_t={budget.time_spent_prev + budget.time_spent_this:9.0f}s "
                   f"wall={time.time()-t0:5.1f}s step={step_s:.3f}s"
-                  + chaos_str, flush=True)
+                  + load_str + chaos_str, flush=True)
             if args.ckpt_dir:
                 fl = state.fl if hcef.overlap else state
                 meta = {"round": rnd}

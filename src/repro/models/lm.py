@@ -12,6 +12,7 @@ from typing import Any, Dict, Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from repro.configs.base import ModelConfig
 from repro.kernels import ops
@@ -108,7 +109,7 @@ def _attention(cfg, x, w, pol, positions, *, causal, window=0, prefix=""):
     k = constrain(pol, k, "kv_full")  # gather over the sequence-shard axis
     v = constrain(pol, v, "kv_full")
     o = ops.flash_attention(q, k, v, causal=causal, window=window,
-                            policy=pol)
+                            softmax_scale=_softmax_scale(cfg), policy=pol)
     o = constrain(pol, o, "heads")
     o = o.reshape(B, S, H * Dh) @ w[prefix + "wo"]
     return constrain(pol, o, "residual"), (k, v)
@@ -121,7 +122,8 @@ def _cross_attention(cfg, x, w, pol, mem_kv):
     q = (x @ w["wxq"]).astype(cd).reshape(B, S, H, Dh)
     k, v = mem_kv  # (B, S_enc, KH, Dh) each, precomputed from encoder output
     q = constrain(pol, q, "heads")
-    o = ops.flash_attention(q, k, v, causal=False, policy=pol)
+    o = ops.flash_attention(q, k, v, causal=False,
+                            softmax_scale=_softmax_scale(cfg), policy=pol)
     o = o.reshape(B, S, H * Dh) @ w["wxo"]
     return constrain(pol, o, "residual")
 
@@ -134,161 +136,136 @@ def _dense_ffn(cfg, x, w, pol, prefix="w"):
     return constrain(pol, h @ w[prefix + "_down"], "residual")
 
 
-# --- MoE dispatch gathers with gather-form VJPs -----------------------------
-# The backward of take_along_axis is a scatter-add, which GSPMD replicates
-# for data-dependent indices.  The MoE dispatch permutations are (masked)
-# bijections, so every cotangent is itself a gather with the inverse index
-# set — these custom VJPs keep the whole fwd+bwd dispatch scatter-free
-# (perf iteration 2, EXPERIMENTS.md §Perf).
+# --- dropless mixture of experts ---------------------------------------------
+# Every (token, expert) assignment is computed: the assignments are sorted
+# by expert, the experts run as grouped matmuls over the ragged groups, and
+# each result is gathered back to its token.  Both permutations are gathers
+# whose cotangents are gathers by the inverse permutation, so neither
+# direction scatters.
 
 def _float0(x):
-    import numpy as _onp
-    return _onp.zeros(x.shape, jax.dtypes.float0)
+    return np.zeros(x.shape, jax.dtypes.float0)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(5,))
-def _perm_gather(x, idx_f, mask_f, idx_b, mask_b, pol):
-    """y[..., j, :] = x[..., idx_f[j], :] * mask_f[j]; bwd uses (idx_b,
-    mask_b) — the inverse (masked) permutation along axis 2.  Both fwd and
-    bwd outputs are constrained block-local so GSPMD never replicates the
-    data-dependent gathers (the only reshard points are the explicit
-    moe_dispatch / moe_return constraints)."""
-    y = jnp.take_along_axis(x, idx_f[..., None], axis=2)
-    y = y * mask_f[..., None].astype(y.dtype)
-    return constrain(pol, y, "moe_tokens")
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def _dispatch(x, token, inv, K):
+    """xs[j] = x[token[j]]: sorted assignment j reads its token's row.  A
+    token's cotangent sums its K assignments', read back through ``inv``
+    (the sorted position of each assignment, in token-major order)."""
+    return x[token]
 
 
-def _perm_gather_fwd(x, idx_f, mask_f, idx_b, mask_b, pol):
-    return _perm_gather(x, idx_f, mask_f, idx_b, mask_b, pol), \
-        (idx_f, mask_f, idx_b, mask_b)
+def _dispatch_fwd(x, token, inv, K):
+    return x[token], (token, inv)
 
 
-def _perm_gather_bwd(pol, res, dy):
-    idx_f, mask_f, idx_b, mask_b = res
-    dy = constrain(pol, dy, "moe_tokens")
-    dx = jnp.take_along_axis(dy, idx_b[..., None], axis=2)
-    dx = dx * mask_b[..., None].astype(dx.dtype)
-    dx = constrain(pol, dx, "moe_tokens")
-    return (dx, _float0(idx_f), _float0(mask_f), _float0(idx_b),
-            _float0(mask_b))
+def _dispatch_bwd(K, res, dxs):
+    token, inv = res
+    d = dxs[inv].reshape(-1, K, dxs.shape[-1])
+    dx = d.astype(jnp.float32).sum(axis=1).astype(dxs.dtype)
+    return dx, _float0(token), _float0(inv)
 
 
-_perm_gather.defvjp(_perm_gather_fwd, _perm_gather_bwd)
+_dispatch.defvjp(_dispatch_fwd, _dispatch_bwd)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
-def _fanout_gather(xb, t_s, inv_order, K, pol):
-    """tv[..., a, :] = xb[..., t_s[a], :]; each token is read K times, so the
-    cotangent is the K-way gather-sum by inv_order (no scatter)."""
-    tv = jnp.take_along_axis(xb, t_s[..., None], axis=2)
-    return constrain(pol, tv, "moe_tokens")
+@jax.custom_vjp
+def _unpermute(ys, inv, order):
+    """ya[a] = ys[inv[a]]: each assignment's result back in token-major
+    order; the cotangent is the gather by ``order``, the inverse of inv."""
+    return ys[inv]
 
 
-def _fanout_fwd(xb, t_s, inv_order, K, pol):
-    return _fanout_gather(xb, t_s, inv_order, K, pol), (t_s, inv_order)
+def _unpermute_fwd(ys, inv, order):
+    return ys[inv], (inv, order)
 
 
-def _fanout_bwd(K, pol, res, dtv):
-    t_s, inv_order = res
-    B, n, A, D = dtv.shape
-    dtv = constrain(pol, dtv, "moe_tokens")
-    d_orig = jnp.take_along_axis(dtv, inv_order[..., None], axis=2)
-    dxb = d_orig.reshape(B, n, A // K, K, D).sum(axis=3)
-    return constrain(pol, dxb, "moe_tokens"), _float0(t_s), _float0(inv_order)
+def _unpermute_bwd(res, dya):
+    inv, order = res
+    return dya[order], _float0(inv), _float0(order)
 
 
-_fanout_gather.defvjp(_fanout_fwd, _fanout_bwd)
+_unpermute.defvjp(_unpermute_fwd, _unpermute_bwd)
 
 
-def _moe_ffn(cfg, x, w, pol):
-    """Group-local expert-parallel MoE via double-argsort dispatch
-    (perf iterations 1-2, EXPERIMENTS.md §Perf).
+def _moe_ffn(cfg, x, w, pol, experts=None):
+    """Dropless top-K mixture of SwiGLU experts; returns (y, load).
 
-    Routing/capacity run WITHIN seq-shard-aligned token blocks (nblk =
-    sequence shards) so every intermediate keeps the activations' sharding,
-    and the dispatch uses ONLY gathers (argsort + take_along_axis — no
-    scatters, which GSPMD replicates for data-dependent indices).  The
-    dispatch tensor X (B, nblk, E, cap, D) is then resharded from the block
-    dim to the expert dim, which lowers to an all-to-all over the model
-    axis: tokens physically travel to their expert's shard (classic EP).
+    Routing is over all E experts: router logits in f32, softmax, top-K,
+    the K gates renormalised to sum to 1 (the softmax over the K chosen
+    logits).  ``experts`` = (first, count) are the experts whose weights
+    ``w`` holds (default all E, first 0): the layer computes their part of
+    the result, as one expert-parallel share does; the assignments of the
+    other experts sort last, ride in the last group and get gate 0.
+    ``load`` (E,) int32 counts the assignments of every expert.
     """
     B, S, D = x.shape
     E, K = cfg.num_experts, cfg.experts_per_token
+    first, count = experts or (0, E)
+    assert w["we_gate"].shape[0] == count, (w["we_gate"].shape, experts)
     cd = dtype_of(cfg.compute_dtype)
-    nblk = pol.seq_blocks() if pol is not None else 1
-    if S % nblk:
-        nblk = 1
-    Sb = S // nblk
-    A = Sb * K  # assignments per block
-    xb = x.reshape(B, nblk, Sb, D)
+    T, A = B * S, B * S * K
+    # a Mosaic kernel cannot be partitioned by GSPMD: on a mesh the
+    # experts run on the jnp route
+    impl = "jnp" if pol is not None and pol.mesh is not None else None
+    xt = x.reshape(T, D).astype(cd)
+    with jax.named_scope("lm.moe_route"):
+        logits = jnp.dot(xt, w["router"], preferred_element_type=jnp.float32)
+        gate, idx = jax.lax.top_k(jax.nn.softmax(logits, axis=-1), K)
+        gate = gate / jnp.maximum(gate.sum(-1, keepdims=True), 1e-9)
+        expert = idx.reshape(A)
+        load = jnp.sum(expert[:, None] == jnp.arange(E), axis=0,
+                       dtype=jnp.int32)
+        local = expert - first
+        mine = (local >= 0) & (local < count)
+        order = jnp.argsort(jnp.where(mine, local, count),
+                            stable=True).astype(jnp.int32)
+        inv = jnp.argsort(order).astype(jnp.int32)
+        sizes = load[first:first + count]
+        sizes = sizes.at[-1].add(A - sizes.sum())
+        xs = _dispatch(xt, order // K, inv, K)
+        gw = jnp.where(mine, gate.reshape(A), 0.0)
 
-    logits = jnp.einsum("bnsd,de->bnse", xb, w["router"],
-                        preferred_element_type=jnp.float32)
-    probs = jax.nn.softmax(logits, axis=-1)
-    gate_vals, gate_idx = jax.lax.top_k(probs, K)  # (B, nblk, Sb, K)
-    gate_vals = gate_vals / jnp.maximum(
-        gate_vals.sum(-1, keepdims=True), 1e-9)
+    # the expert weights constrained in the forward: their gradients then
+    # keep the experts' sharding instead of a whole f32 (E, D, F)
+    # cotangent a layer (16.6 GiB at arctic's size)
+    kinds = {"we_gate": "moe_w_in", "we_up": "moe_w_in",
+             "we_down": "moe_w_out"}
 
-    e_flat = gate_idx.reshape(B, nblk, A)
-    t_flat = jnp.broadcast_to(
-        jnp.repeat(jnp.arange(Sb, dtype=jnp.int32), K), (B, nblk, A))
-    w_flat = gate_vals.reshape(B, nblk, A)
+    def gmm(a, name):
+        return ops.grouped_matmul(a, constrain(pol, w[name], kinds[name]),
+                                  sizes, impl=impl,
+                                  preferred_element_type=jnp.float32)
 
-    order = jnp.argsort(e_flat, axis=-1).astype(jnp.int32)
-    inv_order = jnp.argsort(order, axis=-1).astype(jnp.int32)
-    e_s = jnp.take_along_axis(e_flat, order, -1)
-    t_s = jnp.take_along_axis(t_flat, order, -1)
-    w_s = jnp.take_along_axis(w_flat, order, -1)
-
-    vv = jax.vmap(jax.vmap(lambda a, v: jnp.searchsorted(
-        a, v, side="left").astype(jnp.int32)))
-    eids = jnp.broadcast_to(jnp.arange(E, dtype=jnp.int32), (B, nblk, E))
-    first = vv(e_s, eids)                       # (B, nblk, E)
-    cap = max(8, int(2 * ((A + E - 1) // E)))   # capacity factor 2.0
-
-    # ---- dispatch: X[e, c] = tokens of the c-th assignment of expert e ----
-    slot_src = first[..., None] + jnp.arange(cap, dtype=jnp.int32)
-    src_e = jnp.take_along_axis(
-        e_s, jnp.clip(slot_src, 0, A - 1).reshape(B, nblk, E * cap), -1)
-    valid = (slot_src < A) & (src_e.reshape(B, nblk, E, cap)
-                              == eids[..., None])
-    pos = jnp.arange(A, dtype=jnp.int32)[None, None] \
-        - jnp.take_along_axis(first, e_s, -1)
-    ok = pos < cap
-    slot_of_a = jnp.clip(e_s * cap + pos, 0, E * cap - 1)
-    tv = _fanout_gather(xb, t_s, inv_order, K, pol)  # (B,nblk,A,D)
-    X = _perm_gather(tv, jnp.clip(slot_src, 0, A - 1).reshape(B, nblk, -1),
-                     valid.reshape(B, nblk, -1), slot_of_a, ok, pol)
-    X = X.reshape(B, nblk, E, cap, D).astype(cd)
-    X = constrain(pol, X, "moe_dispatch")  # block->expert reshard (a2a)
-
-    # constrain expert weights in-forward: their GRADIENTS then inherit the
-    # (E->model, D/F->extra) sharding instead of materializing a full f32
-    # (E, D, F) cotangent per layer (16.6 GiB at arctic scale).
-    we_g = constrain(pol, w["we_gate"], "moe_w_in")
-    we_u = constrain(pol, w["we_up"], "moe_w_in")
-    we_d = constrain(pol, w["we_down"], "moe_w_out")
-    g = jax.nn.silu(jnp.einsum("bnecd,edf->bnecf", X, we_g,
-                               preferred_element_type=jnp.float32)).astype(cd)
-    u = jnp.einsum("bnecd,edf->bnecf", X, we_u,
-                   preferred_element_type=jnp.float32).astype(cd)
-    Y = jnp.einsum("bnecf,efd->bnecd", g * u, we_d,
-                   preferred_element_type=jnp.float32).astype(cd)
-    Y = constrain(pol, Y, "moe_return")  # expert->block reshard (a2a back)
-
-    # ---- combine: pure gathers back to tokens (fwd AND bwd) ----
-    Yf = Y.reshape(B, nblk, E * cap, D)
-    ya = _perm_gather(Yf, slot_of_a, ok,
-                      jnp.clip(slot_src, 0, A - 1).reshape(B, nblk, -1),
-                      valid.reshape(B, nblk, -1), pol)
-    ya = ya * (w_s * jnp.where(ok, 1.0, 0.0))[..., None].astype(cd)
-    ya_orig = _perm_gather(ya, inv_order, jnp.ones_like(ok), order,
-                           jnp.ones_like(ok), pol)
-    y = ya_orig.reshape(B, nblk, Sb, K, D).sum(axis=3)
-    y = y.reshape(B, S, D)
+    with jax.named_scope("lm.moe_experts"):
+        h = (jax.nn.silu(gmm(xs, "we_gate")) * gmm(xs, "we_up")).astype(cd)
+        ys = gmm(h, "we_down").astype(cd)
+    with jax.named_scope("lm.moe_combine"):
+        ya = _unpermute(ys, inv, order).reshape(T, K, D)
+        y = (ya.astype(jnp.float32) * gw.reshape(T, K, 1)).sum(axis=1)
+        y = y.astype(cd).reshape(B, S, D)
     if cfg.moe_dense_ff:  # arctic dense-residual branch (parallel)
         y = y + _dense_ffn(cfg, x, w, pol)
-    return constrain(pol, y, "residual")
+    return constrain(pol, y, "residual"), load
+
+
+def _ffn(cfg, h, w, pol):
+    """(FFN output, per-expert load or None)."""
+    if cfg.num_experts:
+        return _moe_ffn(cfg, h, w, pol)
+    return _dense_ffn(cfg, h, w, pol), None
+
+
+def _residual(cfg, x, y):
+    """x + y, the branch scaled by ``residual_multiplier`` where set."""
+    if cfg.residual_multiplier != 1.0:
+        y = y * cfg.residual_multiplier
+    return x + y
+
+
+def _softmax_scale(cfg):
+    """``attention_multiplier`` where set, else the kernels' 1/sqrt(Dh)."""
+    return cfg.attention_multiplier or None
 
 
 def _block(cfg, pol, carry, w, *, causal=True, mem_kv=None):
@@ -297,28 +274,31 @@ def _block(cfg, pol, carry, w, *, causal=True, mem_kv=None):
     with jax.named_scope("lm.attn"):
         attn_out, _ = _attention(cfg, h, w, pol, positions, causal=causal,
                                  window=cfg.window)
-    x = x + attn_out
+    x = _residual(cfg, x, attn_out)
     if mem_kv is not None and "wxq" in w:
         h = rms_norm(x, w["lnx"], cfg.norm_eps)
-        x = x + _cross_attention(cfg, h, w, pol, mem_kv)
+        x = _residual(cfg, x, _cross_attention(cfg, h, w, pol, mem_kv))
     h = rms_norm(x, w["ln2"], cfg.norm_eps)
     with jax.named_scope("lm.mlp"):
-        if cfg.num_experts:
-            ffn = _moe_ffn(cfg, h, w, pol)
-        else:
-            ffn = _dense_ffn(cfg, h, w, pol)
-    x = x + ffn
-    return (constrain(pol, x, "residual"), positions), None
+        ffn, load = _ffn(cfg, h, w, pol)
+    x = _residual(cfg, x, ffn)
+    return (constrain(pol, x, "residual"), positions), load
 
 
 # ---------------------------------------------------------------------------
 # forward / loss
 # ---------------------------------------------------------------------------
 
+def _embed_tokens(cfg, params, tokens):
+    x = params["emb"][tokens].astype(dtype_of(cfg.compute_dtype))
+    if cfg.embedding_multiplier != 1.0:
+        x = x * cfg.embedding_multiplier
+    return x
+
+
 def _embed(cfg, params, batch, pol):
     with jax.named_scope("lm.embed"):
-        tokens = batch["tokens"]
-        x = params["emb"][tokens].astype(dtype_of(cfg.compute_dtype))
+        x = _embed_tokens(cfg, params, batch["tokens"])
         if cfg.frontend == "vit_stub":
             P = cfg.frontend_tokens
             pe = batch["patch_embeds"].astype(x.dtype)
@@ -342,6 +322,8 @@ def _logits(cfg, params, x, pol):
         head = (params["emb"].T if cfg.tie_embeddings
                 else params["out_head"])
         logits = x @ head.astype(x.dtype)
+        if cfg.logits_scaling != 1.0:
+            logits = logits / cfg.logits_scaling
         logits = softcap(logits, cfg.logits_softcap)
         if cfg.vocab_padded != cfg.vocab_size:
             pad_mask = jnp.arange(cfg.vocab_padded) < cfg.vocab_size
@@ -352,6 +334,11 @@ def _logits(cfg, params, x, pol):
 
 def forward(cfg: ModelConfig, params, batch, policy=None):
     """Teacher-forced full-sequence logits. batch: tokens (B,S) [+ frontend]."""
+    return _forward(cfg, params, batch, policy)[0]
+
+
+def _forward(cfg, params, batch, policy):
+    """(logits, per-layer expert load (L, E) int32 or None)."""
     pol = policy
     x = _embed(cfg, params, batch, pol)
     positions = jnp.arange(x.shape[1])
@@ -373,12 +360,18 @@ def forward(cfg: ModelConfig, params, batch, policy=None):
         return _block(cfg, pol, carry, w, causal=True)
     if cfg.remat:
         body = jax.checkpoint(body)
-    (x, _), _ = jax.lax.scan(body, (x, positions), params["layers"])
-    return _logits(cfg, params, x, pol)
+    (x, _), load = jax.lax.scan(body, (x, positions), params["layers"])
+    return _logits(cfg, params, x, pol), load
 
 
 def loss_fn(cfg: ModelConfig, params, batch, policy=None):
-    logits = forward(cfg, params, batch, policy)
+    return loss_and_load(cfg, params, batch, policy)[0]
+
+
+def loss_and_load(cfg: ModelConfig, params, batch, policy=None):
+    """(loss, load): ``load`` is the (E,) int32 count of assignments per
+    expert summed over the layers, None for a model without experts."""
+    logits, load = _forward(cfg, params, batch, policy)
     tokens = batch["tokens"]
     labels = tokens[:, 1:]
     lg = logits[:, :-1]
@@ -387,7 +380,8 @@ def loss_fn(cfg: ModelConfig, params, batch, policy=None):
         pos = jnp.arange(labels.shape[1])
         mask = mask * (pos[None, :] >= cfg.frontend_tokens)
     with jax.named_scope("lm.head"):
-        return cross_entropy(lg, labels, mask)
+        loss = cross_entropy(lg, labels, mask)
+    return loss, None if load is None else load.sum(axis=0)
 
 
 # ---------------------------------------------------------------------------
@@ -458,12 +452,9 @@ def prefill_paged(cfg: ModelConfig, params, batch, cache, page_table,
         h = rms_norm(x, w["ln1"], cfg.norm_eps)
         attn_out, (k_new, v_new) = _attention(
             cfg, h, w, pol, positions, causal=True, window=cfg.window)
-        x = x + attn_out
+        x = _residual(cfg, x, attn_out)
         h = rms_norm(x, w["ln2"], cfg.norm_eps)
-        if cfg.num_experts:
-            x = x + _moe_ffn(cfg, h, w, pol)
-        else:
-            x = x + _dense_ffn(cfg, h, w, pol)
+        x = _residual(cfg, x, _ffn(cfg, h, w, pol)[0])
         return (constrain(pol, x, "residual"), positions), (k_new, v_new)
 
     if cfg.remat:
@@ -515,7 +506,7 @@ def decode_step_paged(cfg: ModelConfig, params, cache, tokens, page_table,
     ps = cache["k"].shape[2]
     kv_len = kv_len.astype(jnp.int32)
     positions = kv_len[:, None]  # (B, 1) per-request rope positions
-    x = params["emb"][tokens].astype(cd)
+    x = _embed_tokens(cfg, params, tokens)
     pj = kv_len // ps
     phys = jnp.take_along_axis(page_table, pj[:, None], axis=1)[:, 0]
     off = kv_len % ps
@@ -545,8 +536,10 @@ def decode_step_paged(cfg: ModelConfig, params, cache, tokens, page_table,
                 v_scale=jax.lax.dynamic_index_in_dim(c["v_scale"], l, 0,
                                                      keepdims=False))
         o_old, m_old, l_old = ops.paged_decode_attention(
-            q, kp, vp, page_table, kv_len, contiguous=contiguous, **scales)
-        o = ops.decode_attention_combine(q, o_old, m_old, l_old, k, v)
+            q, kp, vp, page_table, kv_len, contiguous=contiguous,
+            softmax_scale=_softmax_scale(cfg), **scales)
+        o = ops.decode_attention_combine(q, o_old, m_old, l_old, k, v,
+                                         softmax_scale=_softmax_scale(cfg))
         c = dict(c)
         if quant:
             kq, ks = kv_quantize_int8(k[:, 0])
@@ -560,12 +553,9 @@ def decode_step_paged(cfg: ModelConfig, params, cache, tokens, page_table,
                 k[:, 0].astype(c["k"].dtype))
             c["v"] = c["v"].at[l, phys, off].set(
                 v[:, 0].astype(c["v"].dtype))
-        x = x + o.reshape(B, 1, H * Dh) @ w["wo"]
+        x = _residual(cfg, x, o.reshape(B, 1, H * Dh) @ w["wo"])
         h = rms_norm(x, w["ln2"], cfg.norm_eps)
-        if cfg.num_experts:
-            x = x + _moe_ffn(cfg, h, w, pol)
-        else:
-            x = x + _dense_ffn(cfg, h, w, pol)
+        x = _residual(cfg, x, _ffn(cfg, h, w, pol)[0])
         return (x, c)
 
     x, out = jax.lax.fori_loop(0, L, body, (x, dict(cache)))
@@ -587,7 +577,7 @@ def prefill(cfg: ModelConfig, params, batch, cache, policy=None):
         h = rms_norm(x, w["ln1"], cfg.norm_eps)
         attn_out, (k_new, v_new) = _attention(
             cfg, h, w, pol, positions, causal=True, window=cfg.window)
-        x = x + attn_out
+        x = _residual(cfg, x, attn_out)
         k_l = jax.lax.dynamic_update_slice_in_dim(k_l, k_new, 0, axis=1)
         v_l = jax.lax.dynamic_update_slice_in_dim(v_l, v_new, 0, axis=1)
         out_extra = {}
@@ -597,13 +587,10 @@ def prefill(cfg: ModelConfig, params, batch, cache, policy=None):
             xk = (mem @ w["wxk"]).astype(cd).reshape(B, -1, KH, Dh)
             xv = (mem @ w["wxv"]).astype(cd).reshape(B, -1, KH, Dh)
             h = rms_norm(x, w["lnx"], cfg.norm_eps)
-            x = x + _cross_attention(cfg, h, w, pol, (xk, xv))
+            x = _residual(cfg, x, _cross_attention(cfg, h, w, pol, (xk, xv)))
             out_extra = {"xk": xk, "xv": xv}
         h = rms_norm(x, w["ln2"], cfg.norm_eps)
-        if cfg.num_experts:
-            x = x + _moe_ffn(cfg, h, w, pol)
-        else:
-            x = x + _dense_ffn(cfg, h, w, pol)
+        x = _residual(cfg, x, _ffn(cfg, h, w, pol)[0])
         return (constrain(pol, x, "residual"), positions), {
             "k": k_l, "v": v_l, **out_extra}
 
@@ -633,7 +620,7 @@ def decode_step(cfg: ModelConfig, params, cache, tokens, policy=None):
     L = cfg.num_layers
     cd = dtype_of(cfg.compute_dtype)
     pos = cache["pos"]
-    x = params["emb"][tokens].astype(cd)
+    x = _embed_tokens(cfg, params, tokens)
     positions = jnp.full((B, 1), pos, jnp.int32)
 
     def body(l, carry):
@@ -663,22 +650,21 @@ def decode_step(cfg: ModelConfig, params, cache, tokens, policy=None):
         kv_len = jnp.broadcast_to(
             jnp.minimum(pos, k_all.shape[2]), (B,))
         o_old, m_old, l_old = ops.decode_attention(
-            q, k_l, v_l, kv_len=kv_len, return_stats=True)
-        o = ops.decode_attention_combine(q, o_old, m_old, l_old, k, v)
+            q, k_l, v_l, kv_len=kv_len, softmax_scale=_softmax_scale(cfg),
+            return_stats=True)
+        o = ops.decode_attention_combine(q, o_old, m_old, l_old, k, v,
+                                         softmax_scale=_softmax_scale(cfg))
         k_all = jax.lax.dynamic_update_slice(
             k_all, k[None], (l, 0, slot, 0, 0))
         v_all = jax.lax.dynamic_update_slice(
             v_all, v[None], (l, 0, slot, 0, 0))
-        x = x + o.reshape(B, 1, H * Dh) @ w["wo"]
+        x = _residual(cfg, x, o.reshape(B, 1, H * Dh) @ w["wo"])
         if cfg.enc_layers:
             h = rms_norm(x, w["lnx"], cfg.norm_eps)
-            x = x + _cross_attention(cfg, h, w, pol,
-                                     (cache["xk"][l], cache["xv"][l]))
+            x = _residual(cfg, x, _cross_attention(
+                cfg, h, w, pol, (cache["xk"][l], cache["xv"][l])))
         h = rms_norm(x, w["ln2"], cfg.norm_eps)
-        if cfg.num_experts:
-            x = x + _moe_ffn(cfg, h, w, pol)
-        else:
-            x = x + _dense_ffn(cfg, h, w, pol)
+        x = _residual(cfg, x, _ffn(cfg, h, w, pol)[0])
         return (x, k_all, v_all)
 
     x, k_all, v_all = jax.lax.fori_loop(

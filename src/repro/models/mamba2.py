@@ -126,6 +126,11 @@ def loss_fn(cfg, params, batch, policy=None):
     return cross_entropy(logits[:, :-1], batch["tokens"][:, 1:])
 
 
+def loss_and_load(cfg, params, batch, policy=None):
+    """(loss, None): no experts, so no load (``lm.loss_and_load``)."""
+    return loss_fn(cfg, params, batch, policy), None
+
+
 def init_cache(cfg: ModelConfig, batch_size: int, max_len: int = 0,
                enc_len: int = 0):
     """O(1)-size decode state: conv window + SSM state (no KV cache)."""

@@ -103,6 +103,27 @@ def test_flash_attention_grad_fl_cell(spec, monkeypatch):
     assert text.count('custom_call_target="tpu_custom_call"') == 3
 
 
+def test_grouped_matmul_grad_fl_cell(spec, monkeypatch):
+    """value_and_grad of the grouped matmul's Pallas route at the Granite
+    FL cell's shape: 4 clients vmapped, 8,208 assignments each (2
+    sequences of 513 tokens, top 8) over 32 experts, 1024 -> 512.  The
+    batching rule folds the clients into one launch over 128 groups: the
+    forward, and the backward's input and weight products, 3 kernels."""
+    monkeypatch.setattr(ops, "_interp", lambda: False)
+    C, M, E, D, F = 4, 2 * 513 * 8, 32, 1024, 512
+
+    def loss(x, w, sizes):
+        y = jax.vmap(lambda x, w, s: ops.grouped_matmul(
+            x, w, s, preferred_element_type=jnp.float32,
+            impl="pallas"))(x, w, sizes)
+        return jnp.sum(y)
+
+    text = jax.jit(jax.value_and_grad(loss, argnums=(0, 1))).lower(
+        spec((C, M, D), jnp.bfloat16), spec((C, E, D, F), jnp.bfloat16),
+        spec((C, E), jnp.int32)).compile().as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 3
+
+
 def test_paged_decode_attention(spec):
     B, P, ps = 8, 16, 16
     NP = 1 + B * P
